@@ -279,7 +279,7 @@ class DevProfiler:
     def _heuristic_compiles(self, rec: _Cycle) -> None:
         """No listener API: flag a suspected compile when this bucket's
         device time is an extreme outlier against its best-seen time.
-        Conservative by design (ratio AND absolute floor) — a tunnel
+        Conservative by design (ratio AND absolute floor) — a host
         stall can double a cycle, but a 4× + 250ms excursion on a warmed
         bucket is a compile or something equally dump-worthy."""
         device_s = rec["dispatch_s"] + rec["block_s"]

@@ -48,21 +48,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions: older runtimes (< 0.5)
-    only ship ``jax.experimental.shard_map.shard_map``, whose
-    replication-check kwarg is ``check_rep`` rather than ``check_vma``.
-    Same semantics either way; this shim keeps the solver runnable on
-    the baked-in toolchain."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as esm
-
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
 from kubernetes_tpu.ops.encode import EncodedBatch, EncodedCluster
 from kubernetes_tpu.ops.pallas_solver import (
     LANES,
@@ -375,7 +360,7 @@ def _build_solve(mesh: Mesh, params: SolverParams, r: int, sc: int, t: int,
     node_sharded = P(None, "nodes")
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             P(),                 # sc_meta (replicated)
@@ -537,9 +522,7 @@ class ShardedBackend:
     # pytrees as h2d: the backend accounts its own plane transfers
     # (donated device-resident buffers are NOT uploads). Bytes are
     # handed over via take_transfer_bytes AFTER a successful solve —
-    # the session's charge-only-after-success rule: a failed sharded
-    # chain link's upload must not pollute the cycle of the backend
-    # that actually solved.
+    # the session's charge-only-after-success rule.
     self_accounting = True
 
     def __init__(self, mesh: Optional[Mesh] = None,
@@ -658,9 +641,9 @@ class ShardedBackend:
                            sv=sstatic.sv, donate=donate)
         rep = self._replicated()
         ints, floats = place_podin(pod_ints, pod_floats, sharding=rep)
-        # per-cycle ledgers start fresh: a FAILED earlier solve (chain
-        # demotion, warm abort) must not leak its staging seconds or
-        # byte counts into this cycle's attribution
+        # per-cycle ledgers start fresh: a FAILED earlier solve (a warm
+        # abort) must not leak its staging seconds or byte counts into
+        # this cycle's attribution
         self._cycle_bytes = {}
         self._staging_s = 0.0
         planes, totals = sstate.planes, sstate.totals

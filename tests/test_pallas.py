@@ -45,7 +45,7 @@ def test_kernel_matches_scan(mixed):
     ref = solve_scan(cluster, batch, SolverParams())
     pstatic, pstate = ps.prepare(cluster, batch)
     ints, floats = pack_podin(batch)
-    backend = ps.PallasBackend(interpret=True)
+    backend = ps.PallasBackend()
     got, _ = backend.solve(SolverParams(), pstatic, pstate, ints, floats)
     np.testing.assert_array_equal(ref, got)
 
@@ -58,7 +58,7 @@ def test_kernel_state_carry_across_batches():
     ref = solve_scan(cluster, batch, SolverParams())
 
     pstatic, pstate = ps.prepare(cluster, batch)
-    backend = ps.PallasBackend(interpret=True)
+    backend = ps.PallasBackend()
     outs = []
     pods_all = batch.pods
     for half in (slice(0, 8), slice(8, 16)):

@@ -6,7 +6,7 @@ Covers the contracts the telemetry pipeline rests on:
 - the ``diag:`` line round-trips through the ONE writer
   (``diagfmt.format_*``) and the ONE parser (``diagfmt.parse_diag``);
 - the parser still reads the legacy hand-rolled format frozen into the
-  committed ``BENCH_r01..r05`` tails;
+  committed ``BENCH_r03..r05`` tails;
 - the e2e segment is rendered from the metrics-registry histogram's own
   accessors, so ``diag:`` and ``/metrics`` cannot disagree;
 - a synthetic bench history with a deliberate out-of-band regression
@@ -196,7 +196,7 @@ def _artifact(dirpath, n: int, value: float, runs=None, telemetry=None,
 
 class TestSyntheticTrajectory:
     def test_flags_regression_not_wobble(self, tmp_path):
-        """r3 wobbles -7% (inside the ±30% tunnel band: NOT flagged —
+        """r3 wobbles -7% (inside the ±30% noise band: NOT flagged —
         the r3→r4 false alarm this tool exists to prevent) while r4
         drops -54% (flagged, attributed to its telemetry)."""
         _artifact(tmp_path, 1, 7000.0, runs=[6800.0, 7000.0, 7200.0])

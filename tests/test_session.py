@@ -173,8 +173,9 @@ class TestIncrementalSession:
 
     def test_wide_term_space_falls_back_to_legacy_backend(self):
         """More tracked anti-affinity terms than padded nodes exceeds the
-        planes layout's totals plane; the solve chain must demote to the
-        legacy backend and still schedule everything."""
+        planes layout's totals plane; the session routes the epoch to the
+        legacy backend by shape, before any solve, and still schedules
+        everything."""
         store = ClusterStore()
         for i in range(8):
             store.add_node(
@@ -306,26 +307,19 @@ class TestCarriedBatchRepartition:
         sched.stop()
 
 
-class _FlakyBackend:
-    """Delegating backend that fails its first N prepare() calls —
-    models a transient TPU-tunnel error during rebuild."""
+class _FailingBackend:
+    """Delegating backend whose prepare() raises — a device that refuses
+    the solve (a compile error, a lost chip)."""
 
-    name = "flaky"
+    name = "failing"
 
-    def __init__(self, inner, fail_times):
+    def __init__(self, inner):
         self.inner = inner
-        self.fails_left = fail_times
         self.attempts = 0
 
     def prepare(self, cluster, batch):
         self.attempts += 1
-        if self.fails_left > 0:
-            self.fails_left -= 1
-            raise RuntimeError("transient tunnel flake")
-        return self.inner.prepare(cluster, batch)
-
-    def solve(self, *a):
-        return self.inner.solve(*a)
+        raise RuntimeError("device refused the solve")
 
     def solve_lazy(self, *a):
         return self.inner.solve_lazy(*a)
@@ -334,14 +328,62 @@ class _FlakyBackend:
         return self.inner.materialize(h)
 
 
-class TestDemotionRetry:
-    def test_transient_failure_does_not_demote_forever(self):
-        """ADVICE r1: a backend demoted by a (possibly transient) error
-        must be retried after DEMOTION_RETRY_REBUILDS successful rebuilds
-        instead of staying demoted for the session's lifetime."""
-        from kubernetes_tpu.ops.pallas_solver import XlaPlanesBackend
-        from kubernetes_tpu.ops.session import DEMOTION_RETRY_REBUILDS
+def _failing_scheduler(n_nodes=4, max_batch=4):
+    from kubernetes_tpu.ops.pallas_solver import XlaPlanesBackend
 
+    store = ClusterStore()
+    for i in range(n_nodes):
+        store.add_node(
+            MakeNode().name(f"n{i}")
+            .capacity({"cpu": "64", "memory": "64Gi"}).obj()
+        )
+    sched = Scheduler.create(
+        store, feature_gates=FeatureGates({"TPUBatchScheduler": True}))
+    failing = _FailingBackend(XlaPlanesBackend())
+    bs = attach_batch_scheduler(sched, max_batch=max_batch,
+                                backend=failing)
+    sched.start()
+    return store, sched, bs, failing
+
+
+class TestBackendFailure:
+    def test_failing_backend_raises_out_of_session(self):
+        """No demotion: the chosen backend's exception leaves the
+        session, and the session keeps its backend."""
+        import pytest
+
+        store, sched, bs, failing = _failing_scheduler()
+        pods = [MakePod().name(f"p{i}").uid(f"u{i}")
+                .req({"cpu": "100m"}).obj() for i in range(4)]
+        for attempt in (1, 2):
+            with pytest.raises(RuntimeError, match="refused"):
+                bs.session.solve(pods)
+            assert failing.attempts == attempt
+            assert bs.session.backend is failing
+        sched.stop()
+
+    def test_sidecar_counts_rescued_pods(self, caplog):
+        """The sidecar's safety net binds every popped pod through the
+        serial path, counts each one, and logs the failure at error
+        level."""
+        import logging
+
+        store, sched, bs, failing = _failing_scheduler(max_batch=4)
+        with caplog.at_level(logging.ERROR, logger="kubernetes_tpu.sidecar"):
+            for i in range(12):
+                store.create_pod(MakePod().name(f"p{i}").uid(f"u{i}")
+                                 .req({"cpu": "100m"}).obj())
+            drain(sched, bs)
+        bound = [p for p in store.list_pods() if p.spec.node_name]
+        assert len(bound) == 12
+        assert bs.serial_fallback_pods == 12
+        assert failing.attempts >= 3
+        assert any(r.levelno == logging.ERROR
+                   and "fall back to the serial path" in r.getMessage()
+                   for r in caplog.records)
+        sched.stop()
+
+    def test_healthy_batches_count_no_rescues(self):
         store = ClusterStore()
         for i in range(4):
             store.add_node(
@@ -349,29 +391,11 @@ class TestDemotionRetry:
                 .capacity({"cpu": "64", "memory": "64Gi"}).obj()
             )
         sched, bs = make_batch_scheduler(store, max_batch=4)
-        flaky = _FlakyBackend(XlaPlanesBackend(), fail_times=1)
-        bs.session.backend = flaky
-        bs.session._preferred = flaky
-
-        n = 0
-
-        def pump_one_rebuild():
-            nonlocal n
-            bs.session.invalidate()          # force a rebuild next batch
-            for i in range(4):
-                store.create_pod(MakePod().name(f"w{n}-{i}").uid(f"wu{n}-{i}")
-                                 .req({"cpu": "100m"}).obj())
-            n += 1
-            drain(sched, bs)
-
-        pump_one_rebuild()                   # rebuild 1: flaky fails, demoted
-        assert bs.session.backend.name != "flaky"
-        for _ in range(DEMOTION_RETRY_REBUILDS):
-            pump_one_rebuild()               # cooldown ticks down
-        # preferred backend retried and (flake over) sticks
-        assert bs.session.backend is flaky
-        assert bs.session._active is flaky
-        assert flaky.attempts >= 2
-        bound = [p for p in store.list_pods() if p.spec.node_name]
-        assert len(bound) == 4 * (1 + DEMOTION_RETRY_REBUILDS)
+        for i in range(12):
+            store.create_pod(MakePod().name(f"p{i}").uid(f"u{i}")
+                             .req({"cpu": "100m"}).obj())
+        drain(sched, bs)
+        assert len([p for p in store.list_pods() if p.spec.node_name]) == 12
+        assert bs.serial_fallback_pods == 0
+        assert bs.session.active_backend == bs.session.backend.name
         sched.stop()

@@ -1,6 +1,6 @@
 """Host-path microprofiler: where does each pod's admission + commit
 time go? Runs entirely on CPU with the C++ planes backend so the device
-side is cheap and the HOST costs (BASELINE.md: commit ~70µs/pod,
+side is cheap and the HOST costs (round 5's notes: commit ~70µs/pod,
 admission ~117µs/pod) dominate and are attributable.
 
 Usage:  python tools/profile_host.py [--nodes 1000] [--pods 10000] [--cprofile]
