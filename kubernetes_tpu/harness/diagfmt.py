@@ -312,9 +312,11 @@ def format_mirror(info: Optional[Dict]) -> str:
     total; the tentpole target is near-zero on sustained rows), and
     ``reseeds`` (journal gaps, inexpressible deltas, or topology churn
     forcing a full host rebuild — a sustained row should show none
-    after warmup). Emitted by bench rows whenever the session carries a
-    mirror (``KTPU_MIRROR`` on AND a backend with scatter hooks);
-    parsed by the generic bracket scan in ``parse_diag`` (key
+    after warmup), then one ``reseed.<reason>`` count per reason the
+    rebuilds gave (the cold seed included). Emitted by bench rows
+    whenever the session carries a mirror (``KTPU_MIRROR`` on AND a
+    backend with scatter hooks); parsed by the generic bracket scan in
+    ``parse_diag`` (key
     ``mirror``) — tools/perf_report.py reads it to gate the
     ``mirror_flags`` family."""
     if not info:
@@ -326,6 +328,8 @@ def format_mirror(info: Optional[Dict]) -> str:
     if info.get("encode_share") is not None:
         parts.append(f"encode_share={float(info['encode_share']):.4f}")
     parts.append(f"reseeds={int(info.get('reseeds', 0))}")
+    for reason, n in sorted((info.get("reseed_reasons") or {}).items()):
+        parts.append(f"reseed.{reason}={int(n)}")
     return "mirror[" + " ".join(parts) + "]"
 
 

@@ -55,6 +55,11 @@ SEAM_PREFIX = "seam:"
 PHASE_PRIORITY = ("bind", "commit", "solve", "encode", "queue",
                   "rest", "watch", "seam")
 _PRIO = {p: i for i, p in enumerate(PHASE_PRIORITY)}
+# host stages of a solve: the rebuild and its children, and the mirror
+# catch-up, are host encode work, never device solve time
+_ENCODE_SPANS = frozenset((
+    "solve.encode", "solve.pack", "solve.rebuild", "solve.snapshot",
+    "solve.prepare", "solve.scatter"))
 
 
 def phase_of(name: str) -> Optional[str]:
@@ -63,11 +68,12 @@ def phase_of(name: str) -> Optional[str]:
         return "bind"
     if name == "solve.commit":
         return "commit"
-    if name in ("solve.encode", "solve.pack"):
+    if name in _ENCODE_SPANS:
         return "encode"
-    if name.startswith("solve"):
+    if name.startswith("solve") or name == "sidecar.serial":
         return "solve"
-    if name.startswith("queue"):
+    if name.startswith("queue") or name in ("sidecar.drain",
+                                            "sidecar.discard"):
         return "queue"
     if name.startswith("rest") or name.startswith("route"):
         return "rest"

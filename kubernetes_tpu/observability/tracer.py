@@ -31,6 +31,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 import zlib
@@ -113,12 +114,24 @@ DEFAULT_SAMPLE_RATE = 1.0 / 64.0
 _SAMPLE_DENOM = float(1 << 32)
 
 
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None in a process that has
+    not imported JAX: a span never imports it (apiserver children and
+    spawned workers stay JAX-free)."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    return getattr(profiler, "TraceAnnotation", None)
+
+
 class Span:
     """An in-flight span handle (finished spans live as tuples in the
-    ring). Use via ``Tracer.span(...)`` as a context manager."""
+    ring). Use via ``Tracer.span(...)`` as a context manager. For its
+    lifetime it also holds a ``jax.profiler.TraceAnnotation`` of its
+    name, so a profiler trace shows the span on the calling thread's
+    host line, nested as in the ring."""
 
     __slots__ = ("tracer", "name", "trace", "attrs", "span_id",
-                 "parent_id", "t0")
+                 "parent_id", "t0", "dropped", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, trace: str,
                  attrs: Optional[dict], span_id: int, parent_id: int):
@@ -129,12 +142,21 @@ class Span:
         self.span_id = span_id
         self.parent_id = parent_id
         self.t0 = time.monotonic()
+        self.dropped = False
+        self._annotation = None
 
     def __enter__(self) -> "Span":
         self.tracer._push(self)
+        annotation = _profiler_annotation()
+        if annotation is not None:
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         self.tracer._pop_and_record(self)
         return False
 
@@ -142,6 +164,35 @@ class Span:
         if self.attrs is None:
             self.attrs = {}
         self.attrs.update(attrs)
+
+    def drop(self) -> None:
+        """Leave no record in the ring at exit (a pass that did no
+        work); the profiler annotation, already open, still closes."""
+        self.dropped = True
+
+
+class _NoopSpan:
+    """What a disabled tracer's ``span()`` returns: one shared instance
+    that allocates nothing, draws no id, opens no annotation and
+    propagates no context (``span_id`` reads 0)."""
+
+    __slots__ = ()
+    span_id = 0
+
+    def __enter__(self) -> "_NoopSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def drop(self) -> None:
+        pass
+
+
+NOOP_SPAN = _NoopSpan()
 
 
 class Tracer:
@@ -208,9 +259,11 @@ class Tracer:
                 & 0xFFFFFFFF) < self._sample_cut
 
     # -- recording -----------------------------------------------------
-    def span(self, name: str, trace: str = "", **attrs) -> Span:
+    def span(self, name: str, trace: str = "", **attrs):
         """Open a nested span (context manager). Parent is the innermost
-        open span on this thread."""
+        open span on this thread. Disabled, the shared ``NOOP_SPAN``."""
+        if not self.enabled:
+            return NOOP_SPAN
         parent = 0
         stack = getattr(self._local, "stack", None)
         if stack:
@@ -233,7 +286,7 @@ class Tracer:
             stack.pop()
         elif stack and span in stack:      # out-of-order exit
             stack.remove(span)
-        if not self.enabled:
+        if not self.enabled or span.dropped:
             return
         end = time.monotonic()
         self._append(span.name, _PH_SPAN, end, end - span.t0, span.trace,

@@ -129,14 +129,19 @@ class DeviceClusterMirror:
         self.scatter_bytes_total = 0
         self.reseeds = 0   # full rebuilds AFTER the first seed
         self.seeds = 0
+        # rebuilds (seed and reseeds) by the reason the session gave
+        self.reseed_reasons: Dict[str, int] = {}
+        # why the last catch_up returned None (see catch_up), else None
+        self.last_reason: Optional[str] = None
         self._node_map: Optional[Dict[str, int]] = None
 
     # ------------------------------------------------------------------
-    def note_seeded(self, cold: bool, warming: bool) -> None:
+    def note_seeded(self, cold: bool, warming: bool, reason: str) -> None:
         """Called by the session after every full/state-only rebuild:
         the device planes were just re-seeded from a host encode, so
         the cached node index is stale and — unless this was the cold
-        start or a warm-up — the rebuild counts as a mirror reseed."""
+        start or a warm-up — the rebuild counts as a mirror reseed.
+        ``reason`` is counted in ``reseed_reasons``."""
         self._node_map = None
         if warming:
             return
@@ -144,6 +149,7 @@ class DeviceClusterMirror:
             self.seeds += 1
         else:
             self.reseeds += 1
+        self.reseed_reasons[reason] = self.reseed_reasons.get(reason, 0) + 1
 
     def info(self) -> dict:
         return {
@@ -151,7 +157,13 @@ class DeviceClusterMirror:
             "catch_ups": self.catch_ups,
             "scatter_mb": round(self.scatter_bytes_total / 1e6, 3),
             "reseeds": self.reseeds,
+            "reseed_reasons": dict(self.reseed_reasons),
         }
+
+    def _refuse(self, reason: str) -> None:
+        """Record why the window falls back to a full reseed."""
+        self.last_reason = reason
+        return None
 
     # ------------------------------------------------------------------
     def catch_up(self, from_seq: int, to_seq: int) -> Optional[int]:
@@ -159,25 +171,32 @@ class DeviceClusterMirror:
         resident device planes. Returns the scatter h2d bytes on
         success (0 = the window nets out to nothing), or None when the
         window is inexpressible/gapped — the caller reseeds via the
-        full host encode, which is the mirror-off behavior."""
+        full host encode, which is the mirror-off behavior. On None,
+        ``last_reason`` names the exit: ``no_scatter``, ``cold``,
+        ``journal_gap``, ``translate_error``, ``dispatch_error``, or a
+        record's own (``pod_spread_terms``, ``pod_affinity_terms``,
+        ``pod_volumes``, ``pod_unaligned``, ``node_unknown``,
+        ``node_set_change``, ``node_static_change``, ``external``)."""
         sess = self._session
         backend = sess._active
+        self.last_reason = None
+        if not hasattr(backend, "scatter_state_add"):
+            return self._refuse("no_scatter")
         if (
-            not hasattr(backend, "scatter_state_add")
-            or sess._state is None
+            sess._state is None
             or sess._static is None
             or sess._cluster is None
             or sess._encoder is None
         ):
-            return None
+            return self._refuse("cold")
         recs = self._journal.window(from_seq, to_seq)
         if recs is None:
-            return None
+            return self._refuse("journal_gap")
         try:
             plan = self._translate(recs)
         except Exception:  # noqa: BLE001 — any doubt → full rebuild
             _logger.exception("mirror delta translation failed; reseed")
-            return None
+            return self._refuse("translate_error")
         if plan is None:
             return None
         adds, sets = plan
@@ -190,7 +209,7 @@ class DeviceClusterMirror:
             _logger.exception("mirror scatter dispatch failed; reseed")
             sess.invalidate()
             sess._static_fp = None
-            return None
+            return self._refuse("dispatch_error")
         self.catch_ups += 1
         self.events_applied += len(recs)
         self.scatter_bytes_total += nbytes
@@ -214,7 +233,7 @@ class DeviceClusterMirror:
     ) -> Optional[Tuple[list, dict]]:
         """Journal window → (state add-entries, static set-entries).
         None = some record cannot be expressed bit-exactly against the
-        resident encoding space."""
+        resident encoding space (``last_reason`` says which)."""
         sess = self._session
         static = sess._static
         do, _ = _state_planes(static.r, static.sc, static.t, static.sv)
@@ -231,55 +250,58 @@ class DeviceClusterMirror:
                 # double-count
                 continue
             if k in ("assume", "pod_add"):
-                ok = self._pod_delta(adds, rec.a, +1, do, names, nmap)
+                why = self._pod_delta(adds, rec.a, +1, do, names, nmap)
             elif k == "pod_del":
-                ok = self._pod_delta(adds, rec.a, -1, do, names, nmap)
+                why = self._pod_delta(adds, rec.a, -1, do, names, nmap)
             elif k in ("pod_update", "pod_move"):
-                ok = self._pod_delta(adds, rec.a, -1, do, names, nmap) \
-                    and self._pod_delta(adds, rec.b, +1, do, names, nmap)
+                why = self._pod_delta(adds, rec.a, -1, do, names, nmap) \
+                    or self._pod_delta(adds, rec.b, +1, do, names, nmap)
             elif k == "node_update":
-                ok = self._node_set(sets, rec.a, rec.b, so, names, nmap)
+                why = self._node_set(sets, rec.a, rec.b, so, names, nmap)
+            elif k in ("node_add", "node_del"):
+                why = "node_set_change"
             else:
-                # "external", "node_add", "node_del", unknown kinds:
-                # the node set / arbitrary host state changed
-                ok = False
-            if not ok:
-                return None
+                # "external" and unknown kinds: arbitrary host state
+                # changed
+                why = "external"
+            if why is not None:
+                return self._refuse(why)
         return adds, sets
 
     def _pod_delta(self, out: list, pod, sign: int, do, names,
-                   nmap) -> bool:
+                   nmap) -> Optional[str]:
         """Append (plane row, node col, value) add-entries for one
-        pod's contribution to the dynamic planes; False = reseed."""
+        pod's contribution to the dynamic planes. Returns None, or the
+        reason the pod forces a reseed."""
         node_name = getattr(pod.spec, "node_name", "") or ""
         if not node_name:
-            return True   # unbound pod: no node-plane impact
+            return None   # unbound pod: no node-plane impact
         col = nmap.get(node_name)
         if col is None:
-            return False
+            return "node_unknown"
         enc = self._session._encoder
         # volumes consume CSI attach-column / shared-volume budget —
         # per-claim set semantics the additive model cannot replay
         if enc._attach_col and getattr(pod.spec, "volumes", None):
-            return False
+            return "pod_volumes"
         # pods owning spread/affinity terms contribute to tracked-term
         # registration and anti-term owner counts
         if getattr(pod.spec, "topology_spread_constraints", None):
-            return False
+            return "pod_spread_terms"
         aff = getattr(pod.spec, "affinity", None)
         if aff is not None and (
             getattr(aff, "pod_affinity", None) is not None
             or getattr(aff, "pod_anti_affinity", None) is not None
         ):
-            return False
+            return "pod_affinity_terms"
         # pods MATCHED by a tracked constraint/term land in the
         # sc_counts/term_counts value tables
         for con in (enc._constraints or []):
             if con.matches(pod):
-                return False
+                return "pod_spread_terms"
         for term in (enc._terms or []):
             if term.matches(pod):
-                return False
+                return "pod_affinity_terms"
         pi = PodInfo.of(pod)
         req = pi.resource_request
         nz = pi.non_zero_request
@@ -287,7 +309,7 @@ class DeviceClusterMirror:
         # deltas are exact only on KiB-aligned values
         if req.memory % 1024 or req.ephemeral_storage % 1024 \
                 or nz.memory % 1024:
-            return False
+            return "pod_unaligned"
         # scalar resources outside the tracked column set contribute
         # nothing to the planes at rebuild either — no check needed
         row_vals = _resource_row(req, names)
@@ -299,40 +321,42 @@ class DeviceClusterMirror:
         if nz.memory:
             out.append((do["nonzero"] + 1, col, sign * _kib(nz.memory)))
         out.append((do["pod_count"], col, sign))
-        return True
+        return None
 
-    def _node_set(self, sets: dict, old, new, so, names, nmap) -> bool:
+    def _node_set(self, sets: dict, old, new, so, names,
+                  nmap) -> Optional[str]:
         """SET-entries for a node whose old→new change is confined to
         ``status.allocatable`` (the capacity-churn fast path); anything
-        touching static masks/scores/topology reseeds."""
+        touching static masks/scores/topology reseeds. Returns None, or
+        the reason for the reseed."""
         if old is None or new is None or old.name != new.name:
-            return False
+            return "node_set_change"
         col = nmap.get(new.name)
         if col is None:
-            return False
+            return "node_unknown"
         # attach-limit columns are derived from CSINode state per
         # driver — a capacity scatter would zero them
         if self._session._encoder._attach_col:
-            return False
+            return "node_static_change"
         if (getattr(old.metadata, "labels", None) or {}) != \
                 (getattr(new.metadata, "labels", None) or {}):
-            return False
+            return "node_static_change"
         if bool(getattr(old.spec, "unschedulable", False)) != \
                 bool(getattr(new.spec, "unschedulable", False)):
-            return False
+            return "node_static_change"
         if not _seq_equal(getattr(old.spec, "taints", None),
                           getattr(new.spec, "taints", None)):
-            return False
+            return "node_static_change"
         if not _seq_equal(getattr(old.status, "images", None),
                           getattr(new.status, "images", None)):
-            return False
+            return "node_static_change"
         ni = NodeInfo()
         ni.set_node(new)
         for j, val in enumerate(_resource_row(ni.allocatable, names)):
             sets[(so["alloc"] + j, col)] = val
         sets[(so["max_pods"], col)] = \
             ni.allocatable.allowed_pod_number or 1_000_000
-        return True
+        return None
 
     # ------------------------------------------------------------------
     def _dispatch(self, backend, adds: list, sets: dict) -> int:

@@ -456,7 +456,8 @@ def _get_call(params: SolverParams, r: int, sc: int, t: int, u: int,
     Without the jit wrapper every invocation re-traces and re-lowers the
     kernel (≈1.6s per call); cached, the steady-state call is a single
     executable launch. ``vmem_limit`` (bytes, 0 = the compiler's
-    default) raises the kernel's scoped VMEM limit."""
+    default) raises the kernel's scoped VMEM limit. Kernel and module
+    carry the stable name ``ktpu_solve``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -503,8 +504,13 @@ def _get_call(params: SolverParams, r: int, sc: int, t: int, u: int,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)
         if vmem_limit and not interpret else None,
+        name="ktpu_solve",
     )
-    return jax.jit(call)
+
+    def ktpu_solve(*args):
+        return call(*args)
+
+    return jax.jit(ktpu_solve)
 
 
 def _run(params: SolverParams, pstatic: PStatic, pstate: PState,

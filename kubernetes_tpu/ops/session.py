@@ -37,6 +37,7 @@ import numpy as np
 
 from kubernetes_tpu.observability import get_tracer
 from kubernetes_tpu.observability.devprof import get_devprof
+from kubernetes_tpu.observability.tracer import NOOP_SPAN
 from kubernetes_tpu.ops.encode import BatchEncoder, EncodedCluster
 from kubernetes_tpu.ops.mirror import mirror_enabled
 from kubernetes_tpu.ops.solver import (
@@ -48,6 +49,11 @@ from kubernetes_tpu.ops.solver import (
 )
 
 _logger = logging.getLogger(__name__)
+
+# how a solve refreshed the device state, lightest first: the resident
+# state as it was, a mirror scatter, a state-only re-upload, or a full
+# host re-encode and upload
+REBUILD_KINDS = ("none", "scatter", "state_only", "full")
 
 
 def _tree_nbytes(tree) -> int:
@@ -276,23 +282,10 @@ class SolverSession:
         # scheduling-cycle id stamped by the sidecar before each solve so
         # the per-cycle phase spans correlate with the pods' queue cycles
         self.trace_cycle = -1
-        # optional device profiling (SURVEY.md section 5: JAX profiler /
-        # xplane dumps per solve batch): KTPU_PROFILE_DIR starts a trace
-        # at the first non-warming solve and stops it after
-        # KTPU_PROFILE_BATCHES (default 5) solves
-        import os
-
-        self._profile_dir = os.environ.get("KTPU_PROFILE_DIR") or None
-        try:
-            self._profile_left = int(
-                os.environ.get("KTPU_PROFILE_BATCHES", "5")
-            )
-        except ValueError:
-            _logger.warning("invalid KTPU_PROFILE_BATCHES; profiling off")
-            self._profile_left = 0
-        if self._profile_left <= 0:
-            self._profile_dir = None
-        self._profiling = False
+        # the heaviest state refresh the solves since the sidecar last
+        # reset it took (``REBUILD_KINDS``): the ``queue.cycle`` span's
+        # ``rebuild`` argument
+        self.cycle_rebuild = "none"
         # device-resident cluster mirror (KTPU_MIRROR, default on):
         # watch deltas journaled by the cache are SCATTERED into the
         # donated planes at the next solve instead of forcing a full
@@ -459,9 +452,10 @@ class SolverSession:
         device latency — is the PADDED size, not the real pod count;
         each distinct pad size is its own compiled executable)."""
         self._warming = warming
-        self._profile_tick()
         pad = pad_to or self.max_batch
         seq_before = self.sched.cache.mutation_seq
+        # why this solve cannot stay incremental, once known
+        reason = None
         # mirror catch-up: a journaled mutation window since the last
         # validated seq is scattered into the resident planes, making
         # the incremental gate below pass — external churn stops
@@ -474,11 +468,19 @@ class SolverSession:
             and 0 <= self._last_seq != seq_before
             and self._node_epoch == self.sched.cache.node_set_seq
         ):
-            t_sc = time.monotonic()
-            applied = self._mirror.catch_up(self._last_seq, seq_before)
+            events0 = self._mirror.events_applied
+            with self._span("solve.scatter") as sp:
+                t_sc = time.monotonic()
+                applied = self._mirror.catch_up(self._last_seq, seq_before)
+                sc_s = time.monotonic() - t_sc
+                sp.set(events=self._mirror.events_applied - events0,
+                       bytes=applied or 0)
             if applied is not None:
-                scatter_stash = (time.monotonic() - t_sc, applied)
+                scatter_stash = (sc_s, applied)
                 self._last_seq = seq_before
+                self._note_rebuild("scatter")
+            else:
+                reason = self._mirror.last_reason
         if self._state is not None and seq_before == self._last_seq \
                 and self._node_epoch == self.sched.cache.node_set_seq:
             dp = get_devprof()
@@ -497,21 +499,21 @@ class SolverSession:
                     # in the donated ledger.
                     dp.add_bytes("h2d", sc_bytes)
                     dp.add_bytes("scatter", sc_bytes)
-                if not warming:
-                    self._observe("scatter", sc_s)
+                self._observe("scatter", sc_s)
             try:
                 t0 = time.monotonic()
-                pb = self._encoder.encode_pods_only(pods, pad)
+                with self._span("solve.encode"):
+                    pb = self._encoder.encode_pods_only(pods, pad)
                 if pb is not None and pb.requests.shape[1] == \
                         self._cluster.allocatable.shape[1]:
                     self.last_profile_idx = pb.profile_idx
                     self.last_inexpressible = pb.inexpressible
                     t_pack = time.monotonic()
-                    ints, floats = pack_podin(pb)
+                    with self._span("solve.pack"):
+                        ints, floats = pack_podin(pb)
                     t_done = time.monotonic()
-                    self._observe("encode", t_pack - t0, end_mono=t_pack)
-                    self._observe("pack", t_done - t_pack,
-                                  end_mono=t_done)
+                    self._observe("encode", t_pack - t0)
+                    self._observe("pack", t_done - t_pack)
                     # devprof attribution: the pod-row delta encode is
                     # the drained pods' h2d prep — inherent per-batch
                     # work, booked under pack. The "encode" phase (and
@@ -527,10 +529,11 @@ class SolverSession:
                     # this solve's inputs (never re-encoded host-side)
                     chained = self._dispatch_seq > self._materialize_seq
                     t0 = time.monotonic()
-                    handle, self._state = self._active.solve_lazy(
-                        self.params, self._static, self._state,
-                        ints, floats
-                    )
+                    with self._span("solve.device"):
+                        handle, self._state = self._active.solve_lazy(
+                            self.params, self._static, self._state,
+                            ints, floats
+                        )
                     if chained and not warming:
                         self.carry_chained += 1
                     staging = self._take_staging_s()
@@ -547,7 +550,8 @@ class SolverSession:
                             self._timed_materializer(rec)
                     else:
                         t_b = time.monotonic()
-                        handle = self._active.materialize(handle)
+                        with self._span("solve.block"):
+                            handle = self._active.materialize(handle)
                         dp.phase("block", time.monotonic() - t_b)
                         dp.add_bytes(
                             "d2h", int(getattr(handle, "nbytes", 0)))
@@ -561,6 +565,7 @@ class SolverSession:
                 # the record describes no solve — drop it rather than
                 # pollute the cycle stream with an empty row
                 dp.abort(rec)
+                reason = "batch_shape"
             except BaseException:
                 # encode/solve raised (the sidecar falls back to the
                 # serial path): the record describes no completed solve,
@@ -572,7 +577,34 @@ class SolverSession:
             return None
         # the rebuild path always solves eagerly (rebuilds are rare and
         # the caller just committed any in-flight batch anyway)
-        return self._rebuild_and_solve(pods, seq_before, pad)
+        return self._rebuild_and_solve(
+            pods, seq_before, pad, reason or self._rebuild_reason())
+
+    def _rebuild_reason(self) -> str:
+        """Why a solve that tried no catch-up has to rebuild: the
+        reasons that never reach ``DeviceClusterMirror.catch_up``."""
+        if self._state is None:
+            return "cold"
+        if self._poisoned:
+            return "poisoned"
+        if self._node_epoch != self.sched.cache.node_set_seq:
+            return "node_epoch"
+        # cache mutations the sidecar did not account for, and no
+        # mirror to scatter them (KTPU_MIRROR=off)
+        return "no_mirror"
+
+    def _note_rebuild(self, kind: str) -> None:
+        """Raise ``cycle_rebuild`` to ``kind`` (warm-ups excluded)."""
+        if not self._warming and REBUILD_KINDS.index(kind) > \
+                REBUILD_KINDS.index(self.cycle_rebuild):
+            self.cycle_rebuild = kind
+
+    def _span(self, name: str, **attrs):
+        """A ``solve.*`` span carrying this solve's cycle id; none while
+        warming, so compile time stays out of the phase series."""
+        if self._warming:
+            return NOOP_SPAN
+        return get_tracer().span(name, cycle=self.trace_cycle, **attrs)
 
     def _note_staleness(self, rec, dp) -> None:
         """Snapshot-staleness SLI, once per solve cycle: age of the
@@ -658,11 +690,13 @@ class SolverSession:
         self._dispatch_seq += 1
         token = self._dispatch_seq
         dp = get_devprof()
+        cycle = self.trace_cycle
 
         def _timed(handle):
-            t0 = time.monotonic()
-            out = mat(handle)
-            end = time.monotonic()
+            with get_tracer().span("solve.block", cycle=cycle):
+                t0 = time.monotonic()
+                out = mat(handle)
+                end = time.monotonic()
             if token > self._materialize_seq:
                 self._materialize_seq = token
             try:
@@ -672,10 +706,6 @@ class SolverSession:
                 dp.note_block(rec, end - t0,
                               int(getattr(out, "nbytes", 0)),
                               start_mono=t0)
-                tracer = get_tracer()
-                if tracer.enabled:
-                    tracer.record("solve.block", t0, end,
-                                  cycle=rec["cycle"])
             except Exception:  # noqa: BLE001 — must never break commits
                 pass
             return out
@@ -714,7 +744,7 @@ class SolverSession:
         )
 
     def _rebuild_and_solve(self, pods: List, seq_before: int,
-                           pad: Optional[int] = None):
+                           pad: Optional[int], reason: str):
         if not self._warming:
             self.rebuilds += 1
         self._poisoned = False
@@ -732,7 +762,7 @@ class SolverSession:
             self._note_staleness(rec, dp)
         try:
             return self._rebuild_and_solve_inner(
-                pods, seq_before, pad, dp, rec)
+                pods, seq_before, pad, dp, rec, reason)
         except BaseException:
             # the backend raised (or a keyboard interrupt): the record
             # describes no completed solve
@@ -740,69 +770,84 @@ class SolverSession:
             raise
 
     def _rebuild_and_solve_inner(self, pods: List, seq_before: int,
-                                 pad: Optional[int], dp, rec):
-        t0 = time.monotonic()
+                                 pad: Optional[int], dp, rec, reason: str):
         # for the mirror's reseed accounting: a rebuild with resident
         # state is a re-seed (the mirror failed to keep up); the cold
         # start is just the seed
         mirror_cold = self._state is None
-        # captured BEFORE the snapshot refresh: a node-set change that
-        # races the rebuild bumps mutation_seq too, so the next solve
-        # re-validates either way
-        self._node_epoch = self.sched.cache.node_set_seq
-        self.sched.algorithm.update_snapshot()
-        self._encoder = BatchEncoder(
-            self.sched.algorithm.snapshot, pad_nodes=self.pad_nodes,
-            client=getattr(self.sched, "client", None),
-            # sharded encode: split the node-column fill by the SAME
-            # shard boundaries the mesh solve uses, so a 50k-node plane
-            # never serializes on one host thread before upload
-            node_shards=getattr(self.backend, "encode_shards", 1),
-        )
-        cluster, batch = self._encoder.encode(
-            pods, pad_pods=pad or self.max_batch
-        )
-        self._cluster = cluster
-        self._static_masks_host = batch.static_masks
-        self.last_profile_idx = batch.profile_idx
-        self.last_inexpressible = batch.inexpressible
-        t_pack = time.monotonic()
-        ints, floats = pack_podin(batch)
-        t_done = time.monotonic()
-        self._observe("encode", t_pack - t0, end_mono=t_pack)
-        self._observe("pack", t_done - t_pack, end_mono=t_done)
-        dp.phase("encode", t_pack - t0)
-        dp.phase("pack", t_done - t_pack)
-        dp.add_bytes("h2d", ints.nbytes + floats.nbytes)
+        # the host part of the rebuild (snapshot, encode, pack, plane
+        # upload) is one span, its stages nested inside it
+        with self._span("solve.rebuild", reason=reason) as rebuild:
+            t0 = time.monotonic()
+            # captured BEFORE the snapshot refresh: a node-set change
+            # that races the rebuild bumps mutation_seq too, so the next
+            # solve re-validates either way
+            self._node_epoch = self.sched.cache.node_set_seq
+            with self._span("solve.snapshot"):
+                self.sched.algorithm.update_snapshot()
+            with self._span("solve.encode"):
+                self._encoder = BatchEncoder(
+                    self.sched.algorithm.snapshot,
+                    pad_nodes=self.pad_nodes,
+                    client=getattr(self.sched, "client", None),
+                    # sharded encode: split the node-column fill by the
+                    # SAME shard boundaries the mesh solve uses, so a
+                    # 50k-node plane never serializes on one host thread
+                    # before upload
+                    node_shards=getattr(self.backend, "encode_shards", 1),
+                )
+                cluster, batch = self._encoder.encode(
+                    pods, pad_pods=pad or self.max_batch
+                )
+            self._cluster = cluster
+            self._static_masks_host = batch.static_masks
+            self.last_profile_idx = batch.profile_idx
+            self.last_inexpressible = batch.inexpressible
+            t_pack = time.monotonic()
+            with self._span("solve.pack"):
+                ints, floats = pack_podin(batch)
+            t_done = time.monotonic()
+            self._observe("encode", t_pack - t0)
+            self._observe("pack", t_done - t_pack)
+            dp.phase("encode", t_pack - t0)
+            dp.phase("pack", t_done - t_pack)
+            dp.add_bytes("h2d", ints.nbytes + floats.nbytes)
 
-        # state-only rebuild: when the mutation that invalidated the
-        # mirror touched only DYNAMIC state (mass preemption's victim
-        # deletions, serial binds), the packed static planes are
-        # bit-identical to the resident ones — re-upload just the state
-        # planes and keep the device-resident static (halves the
-        # per-round host→device traffic on the rebuild-heavy paths)
-        fp = self._static_fingerprint(cluster, batch)
-        backend = self._route(cluster, batch)
-        state_only = (
-            self._static is not None
-            and self._active is backend
-            and hasattr(backend, "prepare_state_only")
-            and self._fingerprints_equal(fp, self._static_fp)
-        )
-        t0 = time.monotonic()
-        if state_only:
-            if rec is not None:
-                rec["rebuild"] = "state_only"
-            state = backend.prepare_state_only(cluster, batch)
-        else:
-            self._static_fp = None
-            self._static, state = backend.prepare(cluster, batch)
+            # state-only rebuild: when the mutation that invalidated the
+            # mirror touched only DYNAMIC state (mass preemption's
+            # victim deletions, serial binds), the packed static planes
+            # are bit-identical to the resident ones — re-upload just
+            # the state planes and keep the device-resident static
+            # (halves the per-round host→device traffic on the
+            # rebuild-heavy paths)
+            fp = self._static_fingerprint(cluster, batch)
+            backend = self._route(cluster, batch)
+            state_only = (
+                self._static is not None
+                and self._active is backend
+                and hasattr(backend, "prepare_state_only")
+                and self._fingerprints_equal(fp, self._static_fp)
+            )
+            kind = "state_only" if state_only else "full"
+            rebuild.set(kind=kind)
+            self._note_rebuild(kind)
+            t0 = time.monotonic()
+            with self._span("solve.prepare"):
+                if state_only:
+                    if rec is not None:
+                        rec["rebuild"] = "state_only"
+                    state = backend.prepare_state_only(cluster, batch)
+                else:
+                    self._static_fp = None
+                    self._static, state = backend.prepare(cluster, batch)
         t_disp = time.monotonic()
-        handle, self._state = backend.solve_lazy(
-            self.params, self._static, state, ints, floats
-        )
+        with self._span("solve.device"):
+            handle, self._state = backend.solve_lazy(
+                self.params, self._static, state, ints, floats
+            )
         t_block = time.monotonic()
-        out = backend.materialize(handle)
+        with self._span("solve.block"):
+            out = backend.materialize(handle)
         t_end = time.monotonic()
         staging = self._take_staging_s(backend)
         dp.phase("dispatch", max(0.0, t_block - t_disp - staging))
@@ -826,7 +871,7 @@ class SolverSession:
         # valid-until-next-mutation; the sidecar's note_committed refines
         self._last_seq = seq_before
         if self._mirror is not None:
-            self._mirror.note_seeded(mirror_cold, self._warming)
+            self._mirror.note_seeded(mirror_cold, self._warming, reason)
         return out, cluster, seq_before
 
     @property
@@ -877,57 +922,12 @@ class SolverSession:
         u = self.last_profile_idx[batch_index]
         return self._static_masks_host[u][: self._cluster.num_real_nodes]
 
-    def _profile_tick(self) -> None:
-        if self._profile_dir is None or self._warming:
-            return
-        import jax
-
-        try:
-            if not self._profiling:
-                jax.profiler.start_trace(self._profile_dir)
-                self._profiling = True
-            elif self._profile_left <= 0:
-                self.finish_profiling()
-                return
-            self._profile_left -= 1
-        except Exception:  # pragma: no cover — profiling must never break solves
-            _logger.exception("solver profiling failed; disabled")
-            self._profile_dir = None
-
-    def finish_profiling(self) -> None:
-        """Stop and flush an in-flight profiler trace (also called from
-        the sidecar's shutdown so short runs still get their dump)."""
-        if not self._profiling:
-            return
-        import jax
-
-        try:
-            jax.profiler.stop_trace()
-            _logger.info("solver profile trace written")
-        except Exception:  # pragma: no cover
-            _logger.exception("solver profile stop failed")
-        self._profiling = False
-        self._profile_dir = None
-
-    def _observe(self, segment: str, seconds: float,
-                 end_mono: Optional[float] = None) -> None:
+    def _observe(self, segment: str, seconds: float) -> None:
+        """One solve phase into ``batch_solve_duration{segment}`` (the
+        ``solve.*`` spans carry the same phases on the tracer)."""
         if self._warming:
             return
         try:
             self.sched.metrics.batch_solve_duration.observe(seconds, segment)
         except Exception:  # pragma: no cover — metrics must never break solves
-            pass
-        # per-cycle solver phase span (solve.pack/encode/device): the
-        # latency-breakdown backbone the bench diag, /metrics histogram,
-        # and Perfetto dumps all read from. ``end_mono`` places a phase
-        # that ended BEFORE this call correctly on the dump's timeline
-        # (deriving start from observe time would shift it late).
-        try:
-            tracer = get_tracer()
-            if tracer.enabled:
-                end = end_mono if end_mono is not None \
-                    else time.monotonic()
-                tracer.record(f"solve.{segment}", end - seconds, end,
-                              cycle=self.trace_cycle)
-        except Exception:  # pragma: no cover
             pass

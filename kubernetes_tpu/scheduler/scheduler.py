@@ -368,9 +368,6 @@ class Scheduler:
         if self._watch_handle is not None:
             self._watch_handle.stop()
             self._watch_handle = None
-        if self.batch_scheduler is not None:
-            # flush an in-flight profiler trace on short runs
-            self.batch_scheduler.session.finish_profiling()
         self.recorder.stop()
         self._bind_pool.shutdown(wait=False)
 
@@ -851,12 +848,11 @@ class Scheduler:
         unreserve/forget/requeue per pod (each forget bumps the cache
         mutation counter, which the async path relies on to invalidate
         the device mirror)."""
-        t_bind = time.monotonic()
-        statuses = fwk.run_bind_plugins_bulk(
-            [i[5] for i in bulk], [i[4] for i in bulk],
-            [i[1].suggested_host for i in bulk],
-        )
-        get_tracer().record("bind.bulk", t_bind, pods=len(bulk))
+        with get_tracer().span("bind.bulk", pods=len(bulk)):
+            statuses = fwk.run_bind_plugins_bulk(
+                [i[5] for i in bulk], [i[4] for i in bulk],
+                [i[1].suggested_host for i in bulk],
+            )
         bound: List[Pod] = []
         observed: List[tuple] = []
         committed = 0

@@ -50,6 +50,7 @@ from typing import Dict, List, Optional
 
 _logger = logging.getLogger(__name__)
 
+from kubernetes_tpu.observability import get_tracer
 from kubernetes_tpu.ops.encode import is_host_only
 from kubernetes_tpu.ops.session import SolverSession
 from kubernetes_tpu.ops.solver import SolverParams
@@ -236,6 +237,9 @@ class TPUBatchScheduler:
         # healthy device)
         self.batch_committed_pods = 0
         self.serial_fallback_pods = 0
+        # pods of solved batches thrown away because the mirror drifted
+        # before their commit (each is solved again the same cycle)
+        self.discarded_pods = 0
         # cache mutations the CURRENT cycle's commits performed
         # (accumulated from commit_assignments_bulk's ledger): the
         # session's validity arithmetic must count every sanctioned
@@ -365,34 +369,35 @@ class TPUBatchScheduler:
         return pad
 
     # -- the pipelined loop ---------------------------------------------
-    def _trace_cycle(self, start: float, processed: int,
-                     committed: int) -> None:
-        """Batch-level ``queue.cycle`` span covering one drain → solve →
-        commit pass. Carries no pod trace, so critical-path attribution
-        overlays it at the LOWEST priority: it soaks up the per-pod
-        assembly gaps no specific span covers (pop → solve dispatch,
-        solve handle → pending stamp, guard re-probes between commit
-        chunks) without ever masking encode/solve/commit/bind time.
-        Idle passes (nothing drained, nothing committed) stay silent."""
+    def _close_cycle(self, cycle, processed: int, committed: int,
+                     discarded: int) -> None:
+        """Count one pass and finish its batch-level ``queue.cycle``
+        span (drain → solve → commit). The span carries no pod trace,
+        so critical-path attribution overlays it at the LOWEST
+        priority: it soaks up the per-pod assembly gaps no specific span
+        covers (pop → solve dispatch, solve handle → pending stamp,
+        guard re-probes between commit chunks) without ever masking
+        encode/solve/commit/bind time. Idle passes (nothing drained,
+        committed or discarded) leave no record."""
         self.batch_committed_pods += committed
-        if processed == 0 and committed == 0:
+        self.discarded_pods += discarded
+        if processed == 0 and committed == 0 and discarded == 0:
+            cycle.drop()
             return
-        try:
-            from kubernetes_tpu.observability import get_tracer
-
-            tracer = get_tracer()
-            if tracer.enabled:
-                tracer.record("queue.cycle", start, time.monotonic(),
-                              pods=processed, committed=committed)
-        except Exception:   # noqa: BLE001 — tracing must not break cycles
-            pass
+        cycle.set(pods=processed, committed=committed, discarded=discarded,
+                  rebuild=self.session.cycle_rebuild)
 
     def _run_batch_pipelined(self, pop_timeout: Optional[float]) -> int:
-        sched = self.sched
-        if sched.is_degraded():
+        if self.sched.is_degraded():
             self._degraded_pause(pop_timeout)
             return 0
-        t_cycle = time.monotonic()
+        with get_tracer().span("queue.cycle") as cycle:
+            return self._pipelined_pass(pop_timeout, cycle)
+
+    def _pipelined_pass(self, pop_timeout: Optional[float], cycle) -> int:
+        sched = self.sched
+        tracer = get_tracer()
+        self.session.cycle_rebuild = "none"
         prev = self._pending
         self._pending = None
         self._service_warm_pad()
@@ -407,34 +412,42 @@ class TPUBatchScheduler:
         # object — the divergence that discarded the batch may be the
         # pod itself being deleted or updated (e.g. gaining a PVC)
         # while its batch was in flight.
+        discarded = 0
+        qpis = None
         if prev is not None and not self.session.mirror_current():
-            qpis = []
-            for qpi, cycle in prev["batchable"]:
-                pod = qpi.pod
-                live = sched.client.get_pod(pod.namespace, pod.name)
-                if live is None or live.uid != pod.uid:
-                    continue  # deleted (and maybe recreated) in flight
-                if live is not pod:
-                    qpi.pod_info = PodInfo.of(live)
-                qpis.append((qpi, cycle))
-            prev = None
-        else:
-            # drain stage, hint-gated: the non-blocking peek decides
-            # whether a drain is worth attempting at all — with batch
-            # N−1's commit pending and nothing queued, skip the pop
-            # (and its condition wait) entirely so stage overlap never
-            # parks on an empty queue; with work queued, drain without
-            # waiting. Only a fully idle pipeline blocks for
-            # pop_timeout (the pump loops' idle-wait contract).
-            hint_n, _hint_prio = sched.queue.pending_hint()
-            if prev is not None and hint_n == 0:
+            discarded = len(prev["batchable"])
+            with tracer.span("sidecar.discard", pods=discarded):
                 qpis = []
-            else:
-                qpis = self._drain(
-                    0.0 if (prev is not None or hint_n) else pop_timeout)
+                for qpi, qcycle in prev["batchable"]:
+                    pod = qpi.pod
+                    live = sched.client.get_pod(pod.namespace, pod.name)
+                    if live is None or live.uid != pod.uid:
+                        continue  # deleted (and maybe recreated) in flight
+                    if live is not pod:
+                        qpi.pod_info = PodInfo.of(live)
+                    qpis.append((qpi, qcycle))
+            prev = None
+        with tracer.span("sidecar.drain") as drain:
+            if qpis is None:
+                # drain stage, hint-gated: the non-blocking peek decides
+                # whether a drain is worth attempting at all — with
+                # batch N−1's commit pending and nothing queued, skip
+                # the pop (and its condition wait) entirely so stage
+                # overlap never parks on an empty queue; with work
+                # queued, drain without waiting. Only a fully idle
+                # pipeline blocks for pop_timeout (the pump loops'
+                # idle-wait contract).
+                hint_n, _hint_prio = sched.queue.pending_hint()
+                if prev is not None and hint_n == 0:
+                    qpis = []
+                else:
+                    qpis = self._drain(
+                        0.0 if (prev is not None or hint_n)
+                        else pop_timeout)
+            batchable, serial = self._partition(qpis)
+            if not qpis:
+                drain.drop()
         processed = len(qpis)
-
-        batchable, serial = self._partition(qpis)
 
         committed = 0
         self._cycle_mutations = 0
@@ -523,7 +536,7 @@ class TPUBatchScheduler:
         # the next solve scatters them into the resident planes, without
         # it they invalidate the session for a full rebuild.
         self.session.note_committed(self._cycle_mutations, seq_anchor)
-        self._trace_cycle(t_cycle, processed, committed)
+        self._close_cycle(cycle, processed, committed, discarded)
         return processed
 
     # -- the serialized (kill-switch) loop ------------------------------
@@ -535,15 +548,22 @@ class TPUBatchScheduler:
         ``_commit_pending``); only the overlap is gone. This is the
         differential guard's reference arm and the operational
         kill-switch if the pipeline ever misbehaves in production."""
-        sched = self.sched
-        if sched.is_degraded():
+        if self.sched.is_degraded():
             self._degraded_pause(pop_timeout)
             return 0
-        t_cycle = time.monotonic()
+        with get_tracer().span("queue.cycle") as cycle:
+            return self._serialized_pass(pop_timeout, cycle)
+
+    def _serialized_pass(self, pop_timeout: Optional[float], cycle) -> int:
+        sched = self.sched
+        self.session.cycle_rebuild = "none"
         self._service_warm_pad()
-        qpis = self._drain(pop_timeout)
+        with get_tracer().span("sidecar.drain") as drain:
+            qpis = self._drain(pop_timeout)
+            batchable, serial = self._partition(qpis)
+            if not qpis:
+                drain.drop()
         processed = len(qpis)
-        batchable, serial = self._partition(qpis)
         committed = 0
         self._cycle_mutations = 0
         seq_anchor = sched.cache.mutation_seq
@@ -574,7 +594,7 @@ class TPUBatchScheduler:
                 self._rescue("solve", batchable, serial)
         self._run_serial(serial)
         self.session.note_committed(self._cycle_mutations, seq_anchor)
-        self._trace_cycle(t_cycle, processed, committed)
+        self._close_cycle(cycle, processed, committed, 0)
         return processed
 
     def pipeline_info(self, telemetry: Optional[Dict] = None
@@ -624,17 +644,21 @@ class TPUBatchScheduler:
         return total
 
     def _run_serial(self, serial: List[QueuedPodInfo]) -> None:
+        if not serial:
+            return
         sched = self.sched
         seen = set()
-        for qpi in serial:
-            if qpi.pod.full_name() in seen:
-                continue  # appended both pre- and post-solve-failure
-            seen.add(qpi.pod.full_name())
-            fwk = sched.profiles[qpi.pod.spec.scheduler_name]
-            # a partial batch commit may already have assumed some of these
-            if sched.skip_pod_schedule(fwk, qpi.pod):
-                continue
-            sched.schedule_pod_serial(fwk, qpi)
+        with get_tracer().span("sidecar.serial", pods=len(serial)):
+            for qpi in serial:
+                if qpi.pod.full_name() in seen:
+                    continue  # appended both pre- and post-solve-failure
+                seen.add(qpi.pod.full_name())
+                fwk = sched.profiles[qpi.pod.spec.scheduler_name]
+                # a partial batch commit may already have assumed some
+                # of these
+                if sched.skip_pod_schedule(fwk, qpi.pod):
+                    continue
+                sched.schedule_pod_serial(fwk, qpi)
 
     def warmup(self, sample_pods: Optional[List] = None) -> float:
         """Compile (or cache-load) the solver for this cluster's shapes by
@@ -766,16 +790,36 @@ class TPUBatchScheduler:
                         serial: List[QueuedPodInfo]) -> int:
         """Materialize and commit one solved batch. Returns the number
         of successfully committed pods; declined/rejected pods are
-        appended to ``serial`` or failed directly (mass decline)."""
+        appended to ``serial`` or failed directly (mass decline). The
+        ``solve.commit`` span starts after the materialize (its wait is
+        ``solve.block``)."""
+        batchable = pending["batchable"]
+        start = pending["start"]
+        pad = pending.get("pad", self.max_batch)
+        mat = pending["materializer"] or (lambda h: h)
+        assignments = mat(pending["handle"])
+        with get_tracer().span(
+                "solve.commit", pods=len(batchable),
+                cycle=batchable[0][1] if batchable else -1,
+                pad=pad) as span:
+            t0 = time.monotonic()
+            committed = self._commit_assignments(pending, assignments,
+                                                 serial)
+            now = time.monotonic()
+            span.set(committed=committed)
+        self.sched.metrics.batch_solve_duration.observe(now - t0, "commit")
+        self.max_cycle_s = max(self.max_cycle_s, now - start)
+        self._tune_chunk(pad, now - start)
+        return committed
+
+    def _commit_assignments(self, pending: dict, assignments,
+                            serial: List[QueuedPodInfo]) -> int:
+        """Commit one solved batch's materialized assignments."""
         sched = self.sched
         fwk = sched.profiles["default-scheduler"]
         batchable = pending["batchable"]
         cluster = pending["cluster"]
         start = pending["start"]
-        mat = pending["materializer"] or (lambda h: h)
-        assignments = mat(pending["handle"])
-
-        t0 = time.monotonic()
         committed = 0
         declined: List[tuple] = []  # (batch index, qpi, cycle)
         commits: List[tuple] = []   # (qpi, result, cycle, start)
@@ -1003,21 +1047,6 @@ class TPUBatchScheduler:
                 committed += self._execute_preemption_plans(
                     fwk, plans, pending["start"], serial
                 )
-        now = time.monotonic()
-        sched.metrics.batch_solve_duration.observe(now - t0, "commit")
-        try:
-            from kubernetes_tpu.observability import get_tracer
-
-            tracer = get_tracer()
-            if tracer.enabled:
-                tracer.record("solve.commit", t0, now,
-                              pods=len(batchable), committed=committed,
-                              cycle=batchable[0][1] if batchable else -1,
-                              pad=pending.get("pad", self.max_batch))
-        except Exception:   # noqa: BLE001 — tracing must not break commits
-            pass
-        self.max_cycle_s = max(self.max_cycle_s, now - start)
-        self._tune_chunk(pending.get("pad", self.max_batch), now - start)
         return committed
 
     def _execute_preemption_plans(self, fwk, plans, start,

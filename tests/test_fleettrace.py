@@ -136,6 +136,16 @@ class TestCriticalPath:
         assert phase_of("reshard.freeze") == "seam"
         assert phase_of("upgrade.roll") == "seam"
         assert phase_of("unrelated") is None
+        # the scheduling cycle's host stages: rebuild time is encode,
+        # never device solve
+        for name in ("solve.rebuild", "solve.snapshot", "solve.prepare",
+                     "solve.scatter", "solve.pack"):
+            assert phase_of(name) == "encode", name
+        assert phase_of("solve.block") == "solve"
+        assert phase_of("sidecar.drain") == "queue"
+        assert phase_of("sidecar.discard") == "queue"
+        assert phase_of("sidecar.serial") == "solve"
+        assert phase_of("bind.bulk") == "bind"
 
     def test_priority_sweep_and_unattributed(self):
         t = Tracer(component="t", sample_rate=1.0,

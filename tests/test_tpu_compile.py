@@ -90,7 +90,11 @@ def _compile_kernel(one_chip, n_nodes, sc, t, u):
 @pytest.mark.parametrize("sc_t_u", [1, 8], ids=["sc-t-u-1", "caps"])
 def test_pallas_kernel_compiles_at_5k_nodes(one_chip, sc_t_u):
     compiled = _compile_kernel(one_chip, 5000, sc_t_u, sc_t_u, sc_t_u)
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the stable names a profiler trace shows: module and kernel op
+    assert text.startswith("HloModule jit_ktpu_solve")
+    assert "%ktpu_solve" in text
 
 
 def test_50k_caps_kernel_compiles_under_the_raised_vmem_limit(one_chip):
