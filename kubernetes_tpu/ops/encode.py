@@ -162,6 +162,15 @@ def _build_match_index(items):
     return simple, complex_items
 
 
+def _count_by_domain(row: np.ndarray, codes: np.ndarray,
+                     node_counts: List[int]) -> None:
+    """Add each node's count into its topology value's column of
+    ``row``; nodes without the key (the sentinel column) count nowhere."""
+    sentinel = row.shape[0] - 1
+    np.add.at(row, codes, np.asarray(node_counts, dtype=row.dtype))
+    row[sentinel] = 0
+
+
 @dataclass
 class _TrackedConstraint:
     """One distinct topology-spread constraint shared by batch pods."""
@@ -354,6 +363,11 @@ class BatchEncoder:
         self._num_values: int = 0
         self._con_match_idx = ({}, [])
         self._term_match_idx = ({}, [])
+        # what the last full encode's cluster-side counts read: the pods
+        # the nodes' label-signature counts stood for, and the distinct
+        # signatures matched against the tracked constraints and terms
+        self.count_pods = 0
+        self.count_sigs = 0
         # delta-column pod-plane pool (streaming-scheduler encode
         # stage): the padded pod-side matrices stay RESIDENT between
         # batches, keyed by their shape tuple, and each encode zeroes
@@ -750,6 +764,8 @@ class BatchEncoder:
         b_pad = pb.requests.shape[0]
 
         # -------- cluster-side spread constraint arrays
+        n_nodes = len(self.node_infos)
+        sc_node, term_node = self._node_match_counts(constraints, terms)
         sc = max(len(constraints), 1)
         sc_key_idx = np.zeros(sc, dtype=np.int32)
         sc_max_skew = np.ones(sc, dtype=np.int32)
@@ -760,18 +776,8 @@ class BatchEncoder:
             sc_key_idx[ci] = con.key_idx
             sc_max_skew[ci] = con.max_skew
             sc_hard[ci] = con.hard
-            # existing matching pods per domain value
-            for i, ni in enumerate(self.node_infos):
-                code = topo_codes[i, con.key_idx]
-                if code >= num_values:
-                    continue
-                count = sum(
-                    1
-                    for pi in ni.pods
-                    if pi.pod.metadata.deletion_timestamp is None
-                    and con.matches(pi.pod)
-                )
-                sc_counts[ci, code] += count
+            _count_by_domain(sc_counts[ci],
+                             topo_codes[:n_nodes, con.key_idx], sc_node[ci])
             # eligible domains per profile
             for ui in range(len(profile_pods)):
                 for i in range(len(self.node_infos)):
@@ -787,12 +793,9 @@ class BatchEncoder:
         term_owners = np.zeros((t_n, num_values + 1), dtype=np.int32)
         for ti, term in enumerate(terms):
             term_key_idx[ti] = term.key_idx
-            for i, ni in enumerate(self.node_infos):
-                code = topo_codes[i, term.key_idx]
-                if code >= num_values:
-                    continue
-                count = sum(1 for pi in ni.pods if term.matches(pi.pod))
-                term_counts[ti, code] += count
+            _count_by_domain(term_counts[ti],
+                             topo_codes[:n_nodes, term.key_idx],
+                             term_node[ti])
         node_idx = {ni.node.name: i for i, ni in enumerate(self.node_infos)}
         for ti, owner_node in existing_anti_terms:
             i = node_idx[owner_node.name]
@@ -827,6 +830,42 @@ class BatchEncoder:
             pod_sv=pb.pod_sv,
             num_values=num_values,
         )
+
+    def _node_match_counts(self, constraints: List[_TrackedConstraint],
+                           terms: List[_TrackedTerm]):
+        """Per tracked constraint and per tracked term, the existing pods
+        of each node it counts: a constraint skips terminating pods, a
+        term does not. Read from the nodes' label-signature counts, with
+        one selector match per distinct signature instead of one per
+        pod."""
+        n = len(self.node_infos)
+        sc_node = [[0] * n for _ in constraints]
+        term_node = [[0] * n for _ in terms]
+        hits: Dict[tuple, tuple] = {}
+        pods_read = 0
+        if constraints or terms:
+            for i, ni in enumerate(self.node_infos):
+                for sig, count in ni.label_sig_counts.items():
+                    hit = hits.get(sig)
+                    if hit is None:
+                        ns, labels, terminating = sig
+                        labels = dict(labels)
+                        hit = hits[sig] = (
+                            [ci for ci, con in enumerate(constraints)
+                             if not terminating and ns == con.namespace
+                             and con.selector.matches(labels)],
+                            [ti for ti, term in enumerate(terms)
+                             if ns in term.namespaces
+                             and term.selector.matches(labels)],
+                        )
+                    pods_read += count
+                    for ci in hit[0]:
+                        sc_node[ci][i] += count
+                    for ti in hit[1]:
+                        term_node[ti][i] += count
+        self.count_pods = pods_read
+        self.count_sigs = len(hits)
+        return sc_node, term_node
 
     # ------------------------------------------------------------------
     def encode_pods_only(self, pods: List[Pod],

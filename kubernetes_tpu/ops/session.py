@@ -785,7 +785,7 @@ class SolverSession:
             self._node_epoch = self.sched.cache.node_set_seq
             with self._span("solve.snapshot"):
                 self.sched.algorithm.update_snapshot()
-            with self._span("solve.encode"):
+            with self._span("solve.encode") as enc_span:
                 self._encoder = BatchEncoder(
                     self.sched.algorithm.snapshot,
                     pad_nodes=self.pad_nodes,
@@ -799,6 +799,8 @@ class SolverSession:
                 cluster, batch = self._encoder.encode(
                     pods, pad_pods=pad or self.max_batch
                 )
+                enc_span.set(count_pods=self._encoder.count_pods,
+                             count_sigs=self._encoder.count_sigs)
             self._cluster = cluster
             self._static_masks_host = batch.static_masks
             self.last_profile_idx = batch.profile_idx

@@ -186,6 +186,7 @@ class PodInfo:
         "preferred_anti_affinity_terms",
         "resource_request",
         "non_zero_request",
+        "label_sig",
     )
 
     @classmethod
@@ -222,6 +223,7 @@ class PodInfo:
         pi.preferred_anti_affinity_terms = base.preferred_anti_affinity_terms
         pi.resource_request = base.resource_request
         pi.non_zero_request = base.non_zero_request
+        pi.label_sig = base.label_sig
         pod.__dict__["_pod_info"] = pi
         return pi
 
@@ -249,6 +251,13 @@ class PodInfo:
                     )
         self.resource_request = compute_pod_resource_request(pod)
         self.non_zero_request = compute_pod_resource_request(pod, non_zero=True)
+        # what a spread constraint or an affinity term can ask of the
+        # pod: pods with equal signatures match the same selectors, so
+        # NodeInfo's per-node signature counts stand in for its pods
+        meta = pod.metadata
+        self.label_sig = (meta.namespace,
+                          frozenset((meta.labels or {}).items()),
+                          meta.deletion_timestamp is not None)
 
 
 class QueuedPodInfo:
@@ -314,6 +323,7 @@ class NodeInfo:
         "allocatable",
         "image_states",
         "generation",
+        "label_sig_counts",
     )
 
     def __init__(self, *pods: Pod):
@@ -327,6 +337,8 @@ class NodeInfo:
         self.allocatable = Resource()
         self.image_states: Dict[str, ImageStateSummary] = {}
         self.generation = next_generation()
+        # PodInfo.label_sig -> number of this node's pods carrying it
+        self.label_sig_counts: Dict[tuple, int] = {}
         for p in pods:
             self.add_pod(p)
 
@@ -352,6 +364,8 @@ class NodeInfo:
         self.requested.allowed_pod_number = 0  # not meaningful on requested
         self.non_zero_requested.add(pi.non_zero_request)
         self.used_ports.update(pod_host_ports(pi.pod))
+        sigs = self.label_sig_counts
+        sigs[pi.label_sig] = sigs.get(pi.label_sig, 0) + 1
         self.generation = next_generation()
 
     def remove_pod(self, pod: Pod) -> bool:
@@ -368,6 +382,11 @@ class NodeInfo:
                 ]
                 self.requested.sub(pi.resource_request)
                 self.non_zero_requested.sub(pi.non_zero_request)
+                left = self.label_sig_counts[pi.label_sig] - 1
+                if left:
+                    self.label_sig_counts[pi.label_sig] = left
+                else:
+                    del self.label_sig_counts[pi.label_sig]
                 # recompute ports (cheap; pods-per-node is small)
                 self.used_ports = set()
                 for x in self.pods:
@@ -397,6 +416,7 @@ class NodeInfo:
         self.non_zero_requested = other.non_zero_requested.clone()
         self.allocatable = other.allocatable.clone()
         self.image_states = dict(other.image_states)
+        self.label_sig_counts = dict(other.label_sig_counts)
         self.generation = other.generation
 
 

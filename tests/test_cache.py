@@ -7,6 +7,7 @@ import pytest
 from kubernetes_tpu.scheduler.cache import SchedulerCache
 from kubernetes_tpu.scheduler.node_tree import NodeTree
 from kubernetes_tpu.scheduler.snapshot import Snapshot, new_snapshot
+from kubernetes_tpu.scheduler.types import NodeInfo, PodInfo
 from kubernetes_tpu.testing import MakeNode, MakePod
 
 
@@ -147,3 +148,105 @@ class TestNewSnapshot:
         assert s.num_nodes() == 2
         assert s.get("n1").requested.milli_cpu == 1000
         assert len(s.pods()) == 1
+
+
+def _recount(ni):
+    counts = {}
+    for pi in ni.pods:
+        counts[pi.label_sig] = counts.get(pi.label_sig, 0) + 1
+    return counts
+
+
+class TestLabelSigCounts:
+    """NodeInfo's per-node counts of (namespace, labels, terminating)
+    signatures, which the encoder reads instead of the pods."""
+
+    def _pod(self, uid, ns="default", labels=None, terminating=False):
+        w = MakePod().name(uid).uid(uid).namespace(ns).labels(
+            labels or {}).node("n1")
+        if terminating:
+            w = w.terminating()
+        return w.obj()
+
+    @pytest.mark.parametrize("seed", range(4))
+    def test_table_equals_a_recount_after_adds_and_removes(self, seed):
+        import random
+
+        rng = random.Random(seed)
+        ni = NodeInfo()
+        ni.set_node(make_node("n1"))
+        live = {}
+        for step in range(200):
+            if live and rng.random() < 0.45:
+                uid = rng.choice(sorted(live))
+                assert ni.remove_pod(live.pop(uid))
+            else:
+                uid = f"u{step}"
+                live[uid] = self._pod(
+                    uid, rng.choice(["default", "other"]),
+                    rng.choice([{"a": "1"}, {"a": "2"}, {}]),
+                    terminating=rng.random() < 0.2)
+                ni.add_pod(live[uid])
+            assert ni.label_sig_counts == _recount(ni)
+        assert all(ni.label_sig_counts.values())
+
+    def test_signature_fields(self):
+        pi = PodInfo.of(self._pod("u", "ns1", {"a": "1"}, terminating=True))
+        assert pi.label_sig == ("ns1", frozenset({("a", "1")}), True)
+
+    def test_removing_an_unknown_pod_leaves_it_unchanged(self):
+        ni = NodeInfo(self._pod("u1", labels={"a": "1"}))
+        before = dict(ni.label_sig_counts)
+        assert not ni.remove_pod(self._pod("missing", labels={"a": "1"}))
+        assert ni.label_sig_counts == before
+
+    def test_remove_uses_the_stored_pod(self):
+        """The argument pod may be a later revision: the stored one's
+        signature is the one taken away."""
+        ni = NodeInfo(self._pod("u1", labels={"a": "1"}))
+        assert ni.remove_pod(self._pod("u1", labels={"a": "2"}))
+        assert ni.label_sig_counts == {}
+
+    def test_zero_count_key_is_dropped(self):
+        a, b = self._pod("u1", labels={"a": "1"}), self._pod("u2")
+        ni = NodeInfo(a, b, self._pod("u3", labels={"a": "1"}))
+        assert ni.label_sig_counts[PodInfo.of(a).label_sig] == 2
+        ni.remove_pod(b)
+        assert PodInfo.of(b).label_sig not in ni.label_sig_counts
+        assert list(ni.label_sig_counts.values()) == [2]
+
+    def test_copies_are_independent(self):
+        src = NodeInfo(self._pod("u1", labels={"a": "1"}))
+        clone = src.clone()
+        dst = NodeInfo()
+        dst.copy_from(src)
+        src.add_pod(self._pod("u2", labels={"a": "1"}))
+        clone.add_pod(self._pod("u3", labels={"b": "1"}))
+        assert dst.label_sig_counts == {
+            PodInfo.of(self._pod("x", labels={"a": "1"})).label_sig: 1}
+        for ni in (src, clone, dst):
+            assert ni.label_sig_counts == _recount(ni)
+
+    def test_assumed_copy_shares_the_signature(self):
+        pod = self._pod("u1", labels={"a": "1"})
+        assumed = MakePod().name("u1").uid("u1").labels({"a": "1"}).obj()
+        assert PodInfo.derived(assumed, PodInfo.of(pod)).label_sig == \
+            PodInfo.of(pod).label_sig
+
+    def test_snapshot_follows_cache_churn(self):
+        c = SchedulerCache()
+        c.add_node(make_node("n1"))
+        snap = Snapshot()
+        pods = [self._pod(f"u{i}", labels={"a": str(i % 2)})
+                for i in range(6)]
+        for p in pods:
+            c.add_pod(p)
+        c.update_snapshot(snap)
+        c.remove_pod(pods[0])
+        c.remove_pod(pods[2])
+        c.update_pod(pods[1], self._pod("u1", labels={"a": "1"},
+                                        terminating=True))
+        c.update_snapshot(snap)
+        ni = snap.get("n1")
+        assert ni.label_sig_counts == _recount(ni)
+        assert sum(ni.label_sig_counts.values()) == 4

@@ -122,6 +122,30 @@ def test_pass_span_tree(cluster, tracer):
     assert commit[8]["committed"] == 8 and commit[8]["pods"] == 8
 
 
+def test_encode_span_counts_signatures(cluster, tracer):
+    """A spread rebuild's encode reads the nodes' label-signature
+    counts: all running pods, one signature. The incremental path's
+    pod-row encode reads none and carries neither arg."""
+    store, bs = cluster
+    store.create_pods([_pod(f"p-{k}") for k in range(8)])
+    assert bs.run_batch(pop_timeout=0.0) == 8
+    store.create_pods([_pod(f"q-{k}") for k in range(8)])
+    assert bs.run_batch(pop_timeout=0.0) == 8
+    bs.flush()
+    by_id = {r[5]: r for r in _records(tracer)}
+    encodes = _records(tracer, "solve.encode")
+    rebuilt = [r for r in encodes
+               if _ancestors(r, by_id)[0] == "solve.rebuild"]
+    incremental = [r for r in encodes if r not in rebuilt]
+    assert rebuilt and incremental
+    first = rebuilt[0][8]
+    # the running pods, and nothing the batch brought
+    assert first["count_pods"] == 2 * NODES
+    assert first["count_sigs"] == 1
+    for rec in incremental:
+        assert "count_pods" not in rec[8] and "count_sigs" not in rec[8]
+
+
 def test_idle_pass_leaves_no_record(cluster, tracer):
     _store, bs = cluster
     assert bs.run_batch(pop_timeout=0.0) == 0
