@@ -49,6 +49,12 @@ class CppBackend:
     def materialize(handle):
         return handle
 
+    @staticmethod
+    def warm_state(pstate):
+        """The state as a solve may take it while ``pstate`` stays
+        valid: the solve mutates its planes in place, so a copy."""
+        return PState(planes=pstate.planes.copy())
+
     # -------- device-resident mirror scatter hooks (ops.mirror): the
     # planes are host numpy mutated in place, so a "scatter" is a fancy
     # index update and zero bytes cross any link

@@ -256,6 +256,11 @@ class SolverSession:
         # materializer for the LAST lazy solve's handle (None when the
         # result was returned eagerly, e.g. the rebuild path)
         self.last_materializer = None
+        # (encoding, state) the LAST solve's dispatch consumed, handed
+        # out with its handle: a batch thrown away before its commit
+        # takes its placements back out of the carry with it
+        # (``restore_carry``)
+        self.last_carry = None
         # newest-applied-event anchor the LAST staleness sample was
         # taken against: a retry cycle solving an UNCHANGED snapshot
         # accrues no new staleness debt and must not be sampled (a
@@ -277,6 +282,8 @@ class SolverSession:
         # dispatches; the differential guard and the sustained-arrival
         # cell read it to prove the pipeline actually pipelines.
         self.carry_chained = 0
+        # carries put back for discarded batches (``restore_carry``)
+        self.carry_restores = 0
         self._dispatch_seq = 0      # lazy handles handed out
         self._materialize_seq = 0   # lazy handles consumed
         # scheduling-cycle id stamped by the sidecar before each solve so
@@ -339,18 +346,10 @@ class SolverSession:
                 return None
             ints, floats = pack_podin(pb)
             dp.add_bytes("h2d", ints.nbytes + floats.nbytes)
-            # a backend whose solve DONATES its state buffers (the
-            # mesh-sharded tier) must warm against a disposable clone:
-            # jax-array immutability no longer protects the resident
-            # mirror once the executable aliases inputs into outputs
-            state = self._state
-            clone = getattr(self._active, "warm_state", None)
-            if clone is not None and getattr(self._active, "donate",
-                                             False):
-                state = clone(state)
             t0 = time.monotonic()
             handle, _discarded_state = self._active.solve_lazy(
-                self.params, self._static, state, ints, floats
+                self.params, self._static,
+                self._spare(self._active, self._state), ints, floats
             )
             t_disp = time.monotonic()
             out = self._active.materialize(handle)  # block: compile+run
@@ -371,6 +370,33 @@ class SolverSession:
             return None
         finally:
             dp.end_cycle(rec)
+
+    @staticmethod
+    def _spare(backend, state):
+        """``state`` as ``backend``'s solve may take it while ``state``
+        itself stays valid: a clone (the backend's ``warm_state``) where
+        the solve donates or mutates its state input (the donating mesh
+        tier, the native planes), else the state itself (jax arrays are
+        immutable)."""
+        clone = getattr(backend, "warm_state", None)
+        return state if clone is None else clone(state)
+
+    def restore_carry(self, kept) -> bool:
+        """Put back the carry a discarded batch's solve consumed (its
+        ``last_carry``), so the batch's placements leave the device
+        state and the next catch-up scatters onto the cluster the host
+        cache holds. ``_last_seq`` stays: the kept carry is the state it
+        anchors. With no kept carry, or one from another encoding, the
+        session is invalidated instead and the next solve reseeds.
+        Returns whether the carry was restored."""
+        # the discarded handle is never materialized
+        self._materialize_seq = self._dispatch_seq
+        if kept is None or kept[0] is not self._cluster:
+            self.invalidate()
+            return False
+        self._state = kept[1]
+        self.carry_restores += 1
+        return True
 
     def invalidate(self) -> None:
         """Mark the device mirror diverged. Sticky until the next rebuild:
@@ -528,6 +554,8 @@ class SolverSession:
                     # donating backend aliases the consumed carry into
                     # this solve's inputs (never re-encoded host-side)
                     chained = self._dispatch_seq > self._materialize_seq
+                    self.last_carry = (self._cluster, self._spare(
+                        self._active, self._state))
                     t0 = time.monotonic()
                     with self._span("solve.device"):
                         handle, self._state = self._active.solve_lazy(
@@ -842,6 +870,7 @@ class SolverSession:
                 else:
                     self._static_fp = None
                     self._static, state = backend.prepare(cluster, batch)
+        kept = (cluster, self._spare(backend, state))
         t_disp = time.monotonic()
         with self._span("solve.device"):
             handle, self._state = backend.solve_lazy(
@@ -866,6 +895,7 @@ class SolverSession:
         self._active = backend
         self._static_fp = fp
         self.last_materializer = None  # already materialized
+        self.last_carry = kept
         if state_only and not self._warming:
             self.state_only_rebuilds += 1
         self._observe("device", t_end - t0)

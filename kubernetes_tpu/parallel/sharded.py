@@ -593,17 +593,15 @@ class ShardedBackend:
         return state
 
     def warm_state(self, sstate: SState) -> SState:
-        """Disposable deep copy of the carried state for warm solves:
-        the donated executable CONSUMES its state inputs, so warming
-        against the live mirror would invalidate the resident buffers.
-        Warm cost stays out of measured cycles by the session's
-        contract, so the round-trip copy is fine."""
-        return SState(
-            planes=jax.device_put(np.asarray(sstate.planes),
-                                  self._node_sharding()),
-            totals=jax.device_put(np.asarray(sstate.totals),
-                                  self._replicated()),
-        )
+        """The carried state as a solve may take it while ``sstate``
+        stays valid (warm solves, the session's kept carry): the donated
+        executable CONSUMES its state inputs, so it gets a device-side
+        copy, on the same shardings and dispatched without a host sync.
+        The staging arm consumes nothing: the state itself."""
+        if not self.donate:
+            return sstate
+        return SState(planes=sstate.planes.copy(),
+                      totals=sstate.totals.copy())
 
     def take_staging_s(self) -> float:
         """Consume the synchronous staging seconds of the last solve

@@ -411,12 +411,16 @@ class TPUBatchScheduler:
         # partition as freshly drained ones, against the live store
         # object — the divergence that discarded the batch may be the
         # pod itself being deleted or updated (e.g. gaining a PVC)
-        # while its batch was in flight.
+        # while its batch was in flight. The discarded solve's
+        # placements leave the device carry with it: the session puts
+        # back the carry that solve consumed, so the re-solve's
+        # catch-up scatters the new events onto the cluster as the host
+        # cache holds it (or, with no carry kept, reseeds).
         discarded = 0
         qpis = None
         if prev is not None and not self.session.mirror_current():
             discarded = len(prev["batchable"])
-            with tracer.span("sidecar.discard", pods=discarded):
+            with tracer.span("sidecar.discard", pods=discarded) as span:
                 qpis = []
                 for qpi, qcycle in prev["batchable"]:
                     pod = qpi.pod
@@ -426,6 +430,9 @@ class TPUBatchScheduler:
                     if live is not pod:
                         qpi.pod_info = PodInfo.of(live)
                     qpis.append((qpi, qcycle))
+                restored = self.session.restore_carry(prev.get("carry"))
+                span.set(restored=discarded if restored else 0,
+                         carry="restored" if restored else "invalidated")
             prev = None
         with tracer.span("sidecar.drain") as drain:
             if qpis is None:
@@ -492,6 +499,7 @@ class TPUBatchScheduler:
                     "batchable": batchable,
                     "handle": handle,
                     "materializer": self.session.last_materializer,
+                    "carry": self.session.last_carry,
                     "cluster": cluster,
                     "profiles": self.session.last_profile_idx,
                     "inexpressible": self.session.last_inexpressible,

@@ -175,6 +175,10 @@ def test_discard_after_churn_delete(cluster, tracer):
     assert cycles[-1][8]["pods"] == held
     [discard] = _records(tracer, "sidecar.discard")
     assert discard[8]["pods"] == held
+    # the discarded solve's carry is put back before the re-solve
+    assert discard[8]["carry"] == "restored"
+    assert discard[8]["restored"] == held
+    assert mirror.info()["carry_restores"] == 1
     rebuilds = _records(tracer, "solve.rebuild")
     assert rebuilds[-1][8]["reason"] == "pod_spread_terms"
     after = mirror.info()["reseed_reasons"]

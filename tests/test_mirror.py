@@ -88,11 +88,14 @@ def _gang(w, gangs=2, size=4, cpu="2"):
 
 
 def _run_scenario(scenario: str, seed: int, mirror_on: bool,
-                  monkeypatch, *, devices=None, max_batch=32):
+                  monkeypatch, *, devices=None, max_batch=32,
+                  pipeline=True):
     """One arm of the differential: drive a seeded event sequence and
     return (bound set, mirror info). ``devices`` selects the sharded
-    tier at that mesh width; None = the process default backend."""
+    tier at that mesh width; None = the process default backend.
+    ``pipeline`` False runs the serialized loop (``KTPU_PIPELINE=off``)."""
     monkeypatch.setenv("KTPU_MIRROR", "on" if mirror_on else "off")
+    monkeypatch.setenv("KTPU_PIPELINE", "on" if pipeline else "off")
     rng = np.random.default_rng(seed)
     store = ClusterStore()
     n_nodes = 10
@@ -154,6 +157,26 @@ def _run_scenario(scenario: str, seed: int, mirror_on: bool,
             _set_node_cpu(store, f"n{picks[1]}", "10")
             store.create_pods(_gang(2, gangs=2, size=4))
             _pump(sched, bs)
+        elif scenario == "discard_under_churn":
+            wave(0, 24)
+            bound = [p for p in store.list_pods() if p.spec.node_name]
+            doomed = rng.choice(bound, size=6, replace=False)
+            store.create_pods([
+                MakePod().name(f"w1-p{i}").uid(f"u1-{i}")
+                .req({"cpu": f"{int(rng.integers(1, 6)) * 100}m"}).obj()
+                for i in range(max_batch + max_batch // 2)
+            ])
+            if bs.pipeline_enabled:
+                # one batch solved and held (half a batch more queued)
+                # when the deletes land behind it: the pass after them
+                # discards it and solves its pods again. The serialized
+                # loop holds nothing, so there the deletes land first.
+                bs.run_batch(pop_timeout=0.1)
+                assert bs._pending is not None
+            for p in doomed:
+                store.delete_pod(p.metadata.namespace, p.metadata.name)
+            _pump(sched, bs)
+            wave(2, 16)
         else:  # pragma: no cover - scenario typo guard
             raise AssertionError(scenario)
         info = None
@@ -277,6 +300,122 @@ class TestMirrorDifferential:
             members = [n for (name, n) in on
                        if name.startswith(f"w{w}-g{g}-") and n]
             assert len(members) in (0, size), (w, g, members)
+
+
+class TestDiscardUnderChurn:
+    """A held batch discarded for deletes behind it is solved again as
+    if it had seen them first: mirror on (carry restored, deletes
+    scattered), mirror off (rebuild) and the serialized loop bind
+    alike."""
+
+    @pytest.mark.parametrize("seed", [3, 14, 77])
+    def test_bound_sets_match(self, seed, monkeypatch):
+        on, ion = _run_scenario("discard_under_churn", seed, True,
+                                monkeypatch)
+        off, _ = _run_scenario("discard_under_churn", seed, False,
+                               monkeypatch)
+        serial, _ = _run_scenario("discard_under_churn", seed, True,
+                                  monkeypatch, pipeline=False)
+        assert on == off == serial
+        assert ion["carry_restores"] >= 1
+        assert ion["catch_ups"] > 0
+
+
+def _state_rows(state) -> np.ndarray:
+    """Every plane of a backend's state, one row per plane, as numpy
+    (``PState`` [C, NB, 128]; ``SState`` [C, N] plus its totals)."""
+    return np.concatenate([np.asarray(x).reshape(-1) for x in state])
+
+
+class TestCarryRestore:
+    """Hold a solved batch, delete bound pods behind it, discard the
+    batch: with its carry put back and the deletes scattered, the
+    resident state equals a fresh state-only rebuild on the same cache,
+    bit for bit, on every backend the mirror serves."""
+
+    def _check(self, backend=None):
+        from kubernetes_tpu.ops.encode import BatchEncoder
+        from kubernetes_tpu.ops.pallas_solver import _state_planes
+
+        rng = np.random.default_rng(5)
+        store = ClusterStore()
+        for i in range(10):
+            store.add_node(MakeNode().name(f"n{i}")
+                           .capacity({"cpu": "8", "memory": "16Gi"}).obj())
+        sched, bs = _make_sched(store, max_batch=16, backend=backend)
+        try:
+            def pods(w, count):
+                return [MakePod().name(f"w{w}-p{i}").uid(f"u{w}-{i}")
+                        .req({"cpu": f"{int(rng.integers(1, 6)) * 100}m",
+                              "memory": "256Mi"}).obj()
+                        for i in range(count)]
+
+            store.create_pods(pods(0, 24))
+            _pump(sched, bs)
+            store.create_pods(pods(1, 24))
+            assert bs.run_batch(pop_timeout=0.1) == 16
+            held = bs._pending
+            assert held is not None
+            bound = [p for p in store.list_pods()
+                     if p.spec.node_name and p.metadata.name.startswith("w0")]
+            for p in bound[:5]:
+                store.delete_pod(p.metadata.namespace, p.metadata.name)
+            sess = bs.session
+            assert not sess.mirror_current()
+            static = sess._static
+            do, _ = _state_planes(static.r, static.sc, static.t, static.sv)
+            n = sess._cluster.allocatable.shape[0]
+
+            def pod_count(state):
+                planes = np.asarray(state.planes)
+                return planes.reshape(planes.shape[0], n)[do["pod_count"]]
+
+            phantom = pod_count(sess._state).sum()
+            assert sess.restore_carry(held["carry"])
+            assert sess.carry_restores == 1
+            applied = sess._mirror.catch_up(sess._last_seq,
+                                            sched.cache.mutation_seq)
+            assert applied is not None
+            got = _state_rows(sess._state)
+            restored = pod_count(sess._state).sum()
+
+            sched.algorithm.update_snapshot()
+            cluster, batch = BatchEncoder(
+                sched.algorithm.snapshot, pad_nodes=sess.pad_nodes,
+            ).encode([q.pod for q, _ in held["batchable"]], pad_pods=16)
+            be = sess._active
+            if hasattr(be, "prepare_state_only"):
+                fresh = be.prepare_state_only(cluster, batch)
+            else:
+                _static, fresh = be.prepare(cluster, batch)
+            assert np.array_equal(got, _state_rows(fresh))
+            # the carry left in place held the discarded batch and none
+            # of the deletes
+            assert phantom == restored + 16 + 5
+            assert restored == len([p for p in store.list_pods()
+                                    if p.spec.node_name])
+        finally:
+            sched.stop()
+
+    def test_pallas(self, monkeypatch):
+        monkeypatch.setenv("KTPU_SOLVER", "pallas")
+        self._check()
+
+    def test_native(self):
+        from kubernetes_tpu.ops import native_backend
+
+        self._check(native_backend.CppBackend())
+
+    @pytest.mark.parametrize("devices", [1, 2, 4])
+    def test_sharded_donated(self, devices):
+        import jax
+
+        from kubernetes_tpu.parallel import ShardedBackend, make_mesh
+
+        if len(jax.devices()) < devices:
+            pytest.skip(f"needs {devices} devices")
+        self._check(ShardedBackend(make_mesh(devices, batch_axis=1),
+                                   donate=True))
 
 
 class TestMeshDifferential:
