@@ -395,16 +395,26 @@ class SchedulerCache:
             self._pod_states[key] = _PodState(new)
 
     def remove_pod(self, pod: Pod) -> None:
-        key = get_pod_key(pod)
         with self._lock:
-            state = self._pod_states.get(key)
-            if state is None:
-                return
-            self._mutation_seq += 1
-            self._note("pod_del", state.pod)
-            self._remove_pod_locked(state.pod)
-            del self._pod_states[key]
-            self._assumed_pods.discard(key)
+            self._remove_pod_confirmed_locked(pod)
+
+    def remove_pods(self, pods: List[Pod]) -> None:
+        """Bulk informer-confirmed deletes under one lock (the batched
+        delete delivery): same per-pod semantics as remove_pod."""
+        with self._lock:
+            for pod in pods:
+                self._remove_pod_confirmed_locked(pod)
+
+    def _remove_pod_confirmed_locked(self, pod: Pod) -> None:
+        key = get_pod_key(pod)
+        state = self._pod_states.get(key)
+        if state is None:
+            return
+        self._mutation_seq += 1
+        self._note("pod_del", state.pod)
+        self._remove_pod_locked(state.pod)
+        del self._pod_states[key]
+        self._assumed_pods.discard(key)
 
     def get_pod(self, pod: Pod) -> Optional[Pod]:
         with self._lock:

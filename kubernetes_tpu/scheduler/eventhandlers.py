@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from kubernetes_tpu.api.types import FAILED, SUCCEEDED, Node, Pod
 from kubernetes_tpu.apiserver.store import ADDED, DELETED, MODIFIED, Event
+from kubernetes_tpu.observability import get_tracer
 from kubernetes_tpu.scheduler import events as ev
 # gang (coscheduling) group label; a new member activates unschedulable
 # siblings via the queue's gang wakeup
@@ -93,8 +94,10 @@ class EventHandlers:
                 sched.queue.gang_members_added(groups)
                 add_run.clear()
             if delete_run:
+                with get_tracer().span("cache.remove_pods",
+                                       pods=len(delete_run)):
+                    sched.cache.remove_pods(delete_run)
                 for p in delete_run:
-                    sched.cache.remove_pod(p)
                     if p.metadata.labels.get(GANG_GROUP_LABEL):
                         for fwk in sched.profiles.values():
                             gang = fwk.get_plugin("Coscheduling")

@@ -4,7 +4,7 @@ Behavioral equivalent of the reference's ``pkg/scheduler/framework/types.go``
 (NodeInfo :230-271, Resource :324, PodInfo/AffinityTerm :72-93, QueuedPodInfo
 :45, nextGeneration :282, Add/RemovePod :524-633). These structs are the
 de-facto feature vectors of the system: per-node aggregates as int64
-milli-CPU / bytes plus scalar-resource maps, used-ports sets, image states,
+milli-CPU / bytes plus scalar-resource maps, used-port counts, image states,
 and affinity-specialized pod sublists. The TPU encoder
 (``kubernetes_tpu.ops.encode``) flattens exactly these aggregates into dense
 device arrays, so keeping them columnar-friendly here is deliberate.
@@ -16,7 +16,7 @@ import itertools
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 from kubernetes_tpu.api import labels as labelslib
 from kubernetes_tpu.api.types import (
@@ -296,9 +296,10 @@ def pod_host_ports(pod: Pod) -> List[PortKey]:
     return out
 
 
-def ports_conflict(used: Set[PortKey], wanted: List[PortKey]) -> bool:
+def ports_conflict(used: Iterable[PortKey], wanted: List[PortKey]) -> bool:
     """HostPortInfo.CheckConflict: 0.0.0.0 conflicts with any IP on the
-    same (protocol, port)."""
+    same (protocol, port). ``used`` is NodeInfo.used_ports, whose keys
+    are the node's ports."""
     if not wanted or not used:
         return False
     for ip, proto, port in wanted:
@@ -314,7 +315,8 @@ class NodeInfo:
 
     __slots__ = (
         "node",
-        "pods",
+        "_pods",
+        "_pod_list",
         "pods_with_affinity",
         "pods_with_required_anti_affinity",
         "used_ports",
@@ -328,10 +330,17 @@ class NodeInfo:
 
     def __init__(self, *pods: Pod):
         self.node: Optional[Node] = None
-        self.pods: List[PodInfo] = []
+        # pod uid -> PodInfo, in the order the pods were added: a removal
+        # finds its pod without scanning the node's pods
+        self._pods: Dict[str, PodInfo] = {}
+        # ``pods`` as a list, built on the first read after a change and
+        # never mutated, so copies may share it
+        self._pod_list: Optional[List[PodInfo]] = []
         self.pods_with_affinity: List[PodInfo] = []
         self.pods_with_required_anti_affinity: List[PodInfo] = []
-        self.used_ports: Set[PortKey] = set()
+        # host port -> number of this node's pods declaring it, so a
+        # removal takes away only the leaving pod's own ports
+        self.used_ports: Dict[PortKey, int] = {}
         self.requested = Resource()
         self.non_zero_requested = Resource()
         self.allocatable = Resource()
@@ -341,6 +350,15 @@ class NodeInfo:
         self.label_sig_counts: Dict[tuple, int] = {}
         for p in pods:
             self.add_pod(p)
+
+    @property
+    def pods(self) -> List[PodInfo]:
+        """The node's pods in the order they were added. Read-only: the
+        list is shared with copies of this NodeInfo."""
+        pods = self._pod_list
+        if pods is None:
+            pods = self._pod_list = list(self._pods.values())
+        return pods
 
     def set_node(self, node: Node) -> None:
         self.node = node
@@ -355,7 +373,8 @@ class NodeInfo:
         self.add_pod_info(PodInfo.of(pod))
 
     def add_pod_info(self, pi: PodInfo) -> None:
-        self.pods.append(pi)
+        self._pods[pi.pod.uid] = pi
+        self._pod_list = None
         if _pod_with_affinity(pi):
             self.pods_with_affinity.append(pi)
         if pi.required_anti_affinity_terms:
@@ -363,37 +382,46 @@ class NodeInfo:
         self.requested.add(pi.resource_request)
         self.requested.allowed_pod_number = 0  # not meaningful on requested
         self.non_zero_requested.add(pi.non_zero_request)
-        self.used_ports.update(pod_host_ports(pi.pod))
+        ports = self.used_ports
+        for key in pod_host_ports(pi.pod):
+            ports[key] = ports.get(key, 0) + 1
         sigs = self.label_sig_counts
         sigs[pi.label_sig] = sigs.get(pi.label_sig, 0) + 1
         self.generation = next_generation()
 
     def remove_pod(self, pod: Pod) -> bool:
-        for i, pi in enumerate(self.pods):
-            if pi.pod.uid == pod.uid:
-                self.pods.pop(i)
-                self.pods_with_affinity = [
-                    x for x in self.pods_with_affinity if x.pod.uid != pod.uid
-                ]
-                self.pods_with_required_anti_affinity = [
-                    x
-                    for x in self.pods_with_required_anti_affinity
-                    if x.pod.uid != pod.uid
-                ]
-                self.requested.sub(pi.resource_request)
-                self.non_zero_requested.sub(pi.non_zero_request)
-                left = self.label_sig_counts[pi.label_sig] - 1
-                if left:
-                    self.label_sig_counts[pi.label_sig] = left
-                else:
-                    del self.label_sig_counts[pi.label_sig]
-                # recompute ports (cheap; pods-per-node is small)
-                self.used_ports = set()
-                for x in self.pods:
-                    self.used_ports.update(pod_host_ports(x.pod))
-                self.generation = next_generation()
-                return True
-        return False
+        """Take away the stored pod with ``pod``'s uid (node_info.go
+        RemovePod); False when the node holds no such pod."""
+        uid = pod.uid
+        pi = self._pods.pop(uid, None)
+        if pi is None:
+            return False
+        self._pod_list = None
+        if _pod_with_affinity(pi):
+            self.pods_with_affinity = [
+                x for x in self.pods_with_affinity if x.pod.uid != uid
+            ]
+        if pi.required_anti_affinity_terms:
+            self.pods_with_required_anti_affinity = [
+                x for x in self.pods_with_required_anti_affinity
+                if x.pod.uid != uid
+            ]
+        self.requested.sub(pi.resource_request)
+        self.non_zero_requested.sub(pi.non_zero_request)
+        ports = self.used_ports
+        for key in pod_host_ports(pi.pod):
+            left = ports[key] - 1
+            if left:
+                ports[key] = left
+            else:
+                del ports[key]
+        left = self.label_sig_counts[pi.label_sig] - 1
+        if left:
+            self.label_sig_counts[pi.label_sig] = left
+        else:
+            del self.label_sig_counts[pi.label_sig]
+        self.generation = next_generation()
+        return True
 
     def clone(self) -> "NodeInfo":
         c = NodeInfo()
@@ -406,12 +434,13 @@ class NodeInfo:
         pointers, snapshot.go), so incremental updates must mutate the
         existing object rather than replace it."""
         self.node = other.node
-        self.pods = list(other.pods)
+        self._pods = dict(other._pods)
+        self._pod_list = other._pod_list
         self.pods_with_affinity = list(other.pods_with_affinity)
         self.pods_with_required_anti_affinity = list(
             other.pods_with_required_anti_affinity
         )
-        self.used_ports = set(other.used_ports)
+        self.used_ports = dict(other.used_ports)
         self.requested = other.requested.clone()
         self.non_zero_requested = other.non_zero_requested.clone()
         self.allocatable = other.allocatable.clone()
