@@ -165,7 +165,6 @@ def _diagnose(sched, bs) -> None:
         sess = ""
         devprof_seg = ""
         mesh_seg = ""
-        pipe_seg = ""
         mirror_seg = ""
         if bs is not None:
             sess = " " + diagfmt.format_session(
@@ -174,14 +173,9 @@ def _diagnose(sched, bs) -> None:
 
             dp = get_devprof()
             summary = dp.summary() if dp.enabled else None
-            if dp.enabled:
-                if summary["cycles"] or summary["warm_compiles"]:
-                    devprof_seg = " " + diagfmt.format_devprof(summary)
-                # streaming-pipeline segment: stage depth + how much of
-                # the in-flight device window host work hid (only when
-                # the pipeline is on — the off arm prints nothing)
-                pipe_seg = " " + diagfmt.format_pipeline(
-                    bs.pipeline_info(summary))
+            if dp.enabled and (summary["cycles"]
+                               or summary["warm_compiles"]):
+                devprof_seg = " " + diagfmt.format_devprof(summary)
             # device-mirror segment: watch deltas scattered into the
             # resident planes, their link cost, and the surviving
             # encode share (only when the session carries a mirror —
@@ -307,7 +301,7 @@ def _diagnose(sched, bs) -> None:
             cp, _doc = collect_critical_path()
             crit_seg = diagfmt.format_critpath(cp)
         log(diagfmt.format_diag(
-            segs + [sess.strip(), devprof_seg.strip(), pipe_seg.strip(),
+            segs + [sess.strip(), devprof_seg.strip(),
                     mirror_seg.strip(), mesh_seg.strip(), churn.strip(),
                     autoscale.strip(), apf.strip(), slo_seg, crit_seg]
             + buckets))
@@ -327,9 +321,8 @@ def run_one(key: str, name: str, nodes: int, init_pods: int,
         ops = make_workload(name, nodes=nodes, init_pods=init_pods,
                             measure_pods=measure_pods)
         t0 = time.time()
-        # 4096 measured within noise of 8192 on throughput (solve/commit
-        # overlap hides the extra cycles) while halving the per-cycle p99
-        # contribution — and the p99 budget is part of the headline metric
+        # 4096 measured within noise of 8192 on throughput while
+        # halving the per-cycle p99 contribution — and the p99 budget is part of the headline metric
         batch = run_workload(f"{name}/batch", ops, use_batch=True,
                              max_batch=min(measure_pods, 4096),
                              wait_timeout=1200, progress=log,
@@ -857,8 +850,8 @@ def main() -> None:
         # the streaming-scheduler row (ISSUE 14): the headline-shaped
         # workload arriving OPEN-LOOP at 5k QPS through the replay
         # engine (not pre-created) — p99 arrival→bind is the headline,
-        # the pipeline's overlap_share and the staleness SLO verdict
-        # ride the row as its acceptance surface
+        # zero lost pods and the staleness SLO verdict ride the row as
+        # its acceptance surface
         from kubernetes_tpu.harness.sustained import run_sustained_row
 
         if args.quick:

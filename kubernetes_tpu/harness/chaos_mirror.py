@@ -7,12 +7,12 @@ reference) — and passes only when the two arms land a BIT-IDENTICAL
 placement set with zero lost pods. The scenarios aim the faults at the
 mirror's seams:
 
-- ``node_kill`` — a node dies inside the scatter window: a solve is
-  dispatched and still in flight when the node is deleted, so the
-  suspect-batch discard and the node-set epoch bump both cross the
-  resident planes mid-sequence.
+- ``node_kill`` — a node dies right after a cycle that scattered
+  churned deltas and committed part of a wave, with the rest of the
+  wave still queued, so the node-set epoch bump crosses the resident
+  planes mid-sequence.
 - ``mesh_resize`` — the sharded backend is torn down and re-attached
-  at a different mesh width with pods in flight: the new session must
+  at a different mesh width with pods still queued: the new session must
   cold-seed the mirror from store truth and keep the differential.
 - ``event_storm`` — a mutation storm overflows the delta journal ring
   between two solves: the window reads as a gap, which MUST surface as
@@ -47,11 +47,9 @@ def _pump(sched, bs, timeout: float = 60.0) -> None:
         sched.queue.flush_backoff_completed()
         if bs.run_batch(pop_timeout=0.0):
             continue
-        if sched.queue.pending_active_count() == 0 and \
-                bs._pending is None:
+        if sched.queue.pending_active_count() == 0:
             break
         time.sleep(0.01)
-    bs.flush()
     sched.wait_for_inflight_bindings()
 
 
@@ -150,9 +148,8 @@ def _drive(scenario: str, seed: int, mirror_on: bool, *,
         _pump(sched, bs, timeout=wait_timeout)
 
         if scenario == "node_kill":
-            # churned deltas scatter on the next dispatch; the node
-            # dies while that solve is still in flight — the scatter
-            # window
+            # churned deltas scatter on the next cycle; the node dies
+            # right after it, with the rest of the wave still queued
             churn()
             store.create_pods(make_wave(1, w1))
             bs.run_batch(pop_timeout=0.1)
@@ -177,8 +174,8 @@ def _drive(scenario: str, seed: int, mirror_on: bool, *,
         elif scenario == "mesh_resize":
             from kubernetes_tpu.parallel import ShardedBackend, make_mesh
 
-            # pods in flight across the resize: solve dispatched, then
-            # the backend torn down and re-attached one width up; more
+            # pods queued across the resize: one cycle commits, then
+            # the backend is torn down and re-attached one width up; more
             # churn lands on the re-seeded mirror afterwards
             churn()
             store.create_pods(make_wave(1, w1))

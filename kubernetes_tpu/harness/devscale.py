@@ -155,12 +155,8 @@ def run_devscale_arm(workload: str, nodes: int, pods: int,
     _, dev_batches = seg.get("device", (0.0, 0))
     tel = r.telemetry or {}
     cycles = max(int(tel.get("cycles", 0)), 1)
-    # solve time from the devprof dispatch+block split, NOT the session
-    # "device" histogram segment: with lazy pipelined solves the
-    # histogram measures dispatch only (the block lands cycles later in
-    # the commit pipeline), while devprof attributes the measured block
-    # wait back to the cycle that dispatched it — the same number on
-    # every arm, whichever side of the pipeline the wait surfaces on
+    # solve time from the devprof dispatch+block split, the same
+    # number on every arm
     dev_total = float(tel.get("dispatch_s", 0.0)) \
         + float(tel.get("block_s", 0.0))
     return {

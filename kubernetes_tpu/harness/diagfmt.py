@@ -313,9 +313,7 @@ def format_mirror(info: Optional[Dict]) -> str:
     ``reseeds`` (journal gaps, inexpressible deltas, or topology churn
     forcing a full host rebuild — a sustained row should show none
     after warmup), then one ``reseed.<reason>`` count per reason the
-    rebuilds gave (the cold seed included), and ``carry_restores``
-    (discarded batches whose placements left the carry before the
-    re-solve; present when the info carries it). Emitted by bench rows
+    rebuilds gave (the cold seed included). Emitted by bench rows
     whenever the session carries a mirror (``KTPU_MIRROR`` on AND a
     backend with scatter hooks); parsed by the generic bracket scan in
     ``parse_diag`` (key
@@ -332,8 +330,6 @@ def format_mirror(info: Optional[Dict]) -> str:
     parts.append(f"reseeds={int(info.get('reseeds', 0))}")
     for reason, n in sorted((info.get("reseed_reasons") or {}).items()):
         parts.append(f"reseed.{reason}={int(n)}")
-    if info.get("carry_restores") is not None:
-        parts.append(f"carry_restores={int(info['carry_restores'])}")
     return "mirror[" + " ".join(parts) + "]"
 
 

@@ -468,7 +468,7 @@ def run_federation_storm(
                          f"bound")
             time.sleep(0.1)
         for fleet in fleets.values():
-            fleet.flush()
+            fleet.wait_for_inflight_bindings()
         per_cluster = _per_cluster_latency(engine, clusters)
         with engine._lock:
             bind_final = dict(engine._bind)
@@ -921,7 +921,6 @@ def run_federation_mini_cell(
         for cid, cell in cells.items():
             if victim is not None and cid == victim:
                 continue
-            cell["bs"].flush()
             cell["sched"].wait_for_inflight_bindings(timeout=30.0)
         # the engine observes binds through the watch fan-in, which
         # can lag the store by a delivery tick: settle until the
@@ -1034,7 +1033,6 @@ def run_degradation_differential(
             engine.start()
             _pump_cells(cells, engine, ledger,
                         time.monotonic() + wait_timeout)
-            cells[0]["bs"].flush()
             cells[0]["sched"].wait_for_inflight_bindings(timeout=30.0)
             stats = engine.finish()
             engine = None

@@ -360,11 +360,6 @@ def run_workload(
                 pump_until_quiescent(time.monotonic() + wait_timeout)
             else:
                 raise ValueError(f"unknown opcode {opcode!r}")
-        if bs is not None:
-            # the wait_names early-return can leave one solved batch of
-            # earlier ops' retried pods uncommitted in the pipeline;
-            # commit it before declaring the run over
-            bs.flush()
         sched.wait_for_inflight_bindings(timeout=30.0)
         duration = time.monotonic() - measure_start if measure_start else 0.0
         if result_hook is not None:

@@ -479,8 +479,6 @@ def run_workload_rest(
                                time.monotonic() + wait_timeout)
             elif opcode == "barrier":
                 pump_until(expected_bound, time.monotonic() + wait_timeout)
-        if bs is not None:
-            bs.flush()
         sched.wait_for_inflight_bindings(timeout=30.0)
         duration = time.monotonic() - measure_start if measure_start \
             else 0.0

@@ -395,7 +395,7 @@ def run_scale_arm_rest(
             raise TimeoutError(
                 f"scale10x[p{partitions}]: bound {rs.bound_count()}"
                 f"/{pods} before deadline")
-        rs.flush()
+        rs.wait_for_inflight_bindings()
         elapsed = time.monotonic() - t0
         collector.stop()
 
@@ -546,7 +546,7 @@ def run_scale_arm_inproc(
             if bound >= pods and rs.pending_count() == 0:
                 break
             time.sleep(0.05)
-        rs.flush()
+        rs.wait_for_inflight_bindings()
         store.drain()
         elapsed = time.monotonic() - t0
 

@@ -1,29 +1,24 @@
-"""Sustained-arrival bench row: the streaming scheduler's proof
-surface.
+"""Sustained-arrival bench row: open-loop arrivals through the batch
+path.
 
 Every store-direct row before this one pre-created its pods in one
-burst, so per-pod latency was batch-amortized and the solve loop's
-barrier never showed up in a committed number. This harness drives the
-headline-shaped workload OPEN-LOOP through the PR 11 replay engine —
+burst, so per-pod latency was batch-amortized and the cycle length
+never showed up in a committed number. This harness drives the
+headline-shaped workload OPEN-LOOP through the replay engine —
 pods arrive on a clock at a target QPS (default 5k/s, the REST rows'
 client discipline), binds are observed on the engine's own watch
 stream, and the row's headline is **p99 arrival→bind latency**: the
-number a submitting user experiences, which the old drain→encode→
-solve→commit barrier quantized at whole-cycle granularity.
+number a submitting user experiences.
 
-The row also carries the pipeline's own verdict surface:
+The row also carries its verdict surface:
 
-- ``telemetry.overlap_share`` — the fraction of the in-flight device
-  window hidden under host work (devprof's per-cycle ``overlap_s``;
-  0.0 would mean the pipeline degenerated back to the barrier);
-- ``freshness.slo.snapshot_staleness`` — PR 8's staleness SLI stays
-  green only if the pipeline's deeper in-flight window never lets the
-  solve run against a stale mirror;
+- ``freshness.slo.snapshot_staleness`` — the staleness SLI stays
+  green only if no solve runs against a stale mirror;
 - ``lost_pods`` — the replay engine's zero-lost quiesce invariant.
 
 ``run_sustained_cell`` is the tier-1 face: a small, time-compressed
-cell asserting overlap actually occurs and the staleness SLO holds,
-cheap enough for the fast suite. ``tools/perf_report.py`` gates the
+cell asserting the staleness SLO holds and nothing is lost, cheap
+enough for the fast suite. ``tools/perf_report.py`` gates the
 committed rows (``sustained_flags``): p99 arrival→bind > 500 ms, lost
 pods, or a red staleness verdict all fail ``--strict``.
 """
@@ -44,8 +39,8 @@ P99_ARRIVAL_TO_BIND_BUDGET_MS = 500.0
 def build_sustained_trace(seed: int, pods: int,
                           qps: float = SUSTAINED_QPS) -> Trace:
     """Open-loop steady arrival trace: ``pods`` Poisson arrivals at
-    ``qps`` (no burst epochs — the row isolates the pipeline, not the
-    burst absorber), lightly heavy-tailed cpu sizes so pad buckets see
+    ``qps`` (no burst epochs — the row isolates the steady cycle, not
+    the burst absorber), lightly heavy-tailed cpu sizes so pad buckets see
     realistic occupancy, NO lifetimes (zero-lost is then exactly
     "every arrival bound"). Deterministic per (seed, pods, qps) — the
     trace.py contract."""
@@ -106,14 +101,12 @@ def run_sustained_once(
     *,
     node_cpu: int = 32,
     max_batch: int = 4096,
-    pipeline: Optional[bool] = None,
     wait_timeout: float = 600.0,
     progress: Optional[Callable[[str], None]] = None,
 ):
     """One open-loop run against an in-process store. Returns
     ``(stats, extras)`` — the replay engine's postmortem plus the
-    telemetry/freshness/pipeline sub-objects. ``pipeline=False`` is
-    the barrier arm (the ``KTPU_PIPELINE=off`` loop)."""
+    telemetry/freshness/mirror sub-objects."""
     from kubernetes_tpu.api.types import Node
     from kubernetes_tpu.apiserver.store import ClusterStore
     from kubernetes_tpu.config.feature_gates import FeatureGates
@@ -140,8 +133,7 @@ def run_sustained_once(
     gates = FeatureGates({"TPUBatchScheduler": True})
     sched = Scheduler.create(store, feature_gates=gates,
                              provider="GangSchedulingProvider")
-    bs = attach_batch_scheduler(sched, max_batch=max_batch,
-                                pipeline=pipeline)
+    bs = attach_batch_scheduler(sched, max_batch=max_batch)
     attach_slo_baseline(sched)
     sched.start()
     engine = None
@@ -156,7 +148,6 @@ def run_sustained_once(
         engine.start()
         _pump_to_quiesce(sched, bs, engine,
                          time.monotonic() + wait_timeout)
-        bs.flush()
         sched.wait_for_inflight_bindings(timeout=30.0)
         wall = time.monotonic() - t0
         stats = engine.finish()
@@ -167,12 +158,10 @@ def run_sustained_once(
             "wall_s": round(wall, 2),
             "telemetry": telemetry,
             "freshness": collect_freshness(telemetry),
-            "pipeline": bs.pipeline_info(telemetry),
             "mirror": bs.mirror_info(telemetry),
             "session": {
                 "incremental_hits": bs.session.incremental_hits,
                 "rebuilds": bs.session.rebuilds,
-                "carry_chained": bs.session.carry_chained,
             },
         }
         return stats, extras
@@ -204,8 +193,7 @@ def run_sustained_row(
 ) -> dict:
     """The committed sustained-arrival row (``bench.py --config
     sustained``). Headline = arrival→bind p99 next to rate-normalized
-    throughput; verdict surface = zero lost + staleness SLO + overlap
-    actually occurring."""
+    throughput; verdict surface = zero lost + staleness SLO."""
     trace = build_sustained_trace(seed, pods, qps)
     n_nodes = len(sustained_nodes(trace, node_cpu=node_cpu))
     if progress:
@@ -243,7 +231,6 @@ def run_sustained_row(
         "lost_pods": stats.lost,
         "invariants": {"zero_lost_pods": zero_lost},
         "invariants_ok": zero_lost,
-        "pipeline": extras.get("pipeline"),
         "mirror": extras.get("mirror"),
         "session": extras.get("session"),
     }
@@ -259,13 +246,10 @@ def run_sustained_row(
             all(v == "ok" for v in slo.values()) if slo else None)
         row["slo_gated"] = sorted(slo)
     if progress:
-        pipe = extras.get("pipeline") or {}
         progress(f"[sustained] {stats.ever_bound}/{stats.injected} "
                  f"bound, p99 arrival→bind "
                  f"{row['p99_arrival_to_bind_ms']}ms, lost "
-                 f"{stats.lost}, overlap_share "
-                 f"{pipe.get('overlap', 0.0):.2f}, depth "
-                 f"{pipe.get('depth', 0)}")
+                 f"{stats.lost}")
     return row
 
 
@@ -274,11 +258,9 @@ def _sustained_diag(extras: Dict) -> None:
 
     from kubernetes_tpu.harness import diagfmt
 
-    segs = [diagfmt.format_pipeline(extras.get("pipeline")),
-            diagfmt.format_mirror(extras.get("mirror"))]
-    segs = [s for s in segs if s]
-    if segs:
-        print(diagfmt.format_diag(segs), file=sys.stderr, flush=True)
+    seg = diagfmt.format_mirror(extras.get("mirror"))
+    if seg:
+        print(diagfmt.format_diag([seg]), file=sys.stderr, flush=True)
 
 
 def run_sustained_cell(
@@ -288,21 +270,18 @@ def run_sustained_cell(
     *,
     node_cpu: int = 16,
     max_batch: int = 64,
-    pipeline: Optional[bool] = None,
     wait_timeout: float = 120.0,
     progress: Optional[Callable[[str], None]] = None,
 ) -> dict:
     """The tier-1 mini-cell: a small open-loop run (compressed scale,
-    small pad bucket so several pipeline cycles occur) returning just
-    the verdict surface — overlap share, staleness SLO verdict, lost
-    count, p99. The fast suite asserts ``overlap_share > 0`` (the
-    pipeline genuinely overlaps) and the staleness verdict stays
-    green, inside the tier-1 time budget."""
+    small pad bucket so several cycles occur) returning just the
+    verdict surface — staleness SLO verdict, lost count, p99, encode
+    share. The fast suite asserts nothing is lost and the staleness
+    verdict stays green, inside the tier-1 time budget."""
     trace = build_sustained_trace(seed, pods, qps)
     stats, extras = run_sustained_once(
         trace, node_cpu=node_cpu, max_batch=max_batch,
-        pipeline=pipeline, wait_timeout=wait_timeout,
-        progress=progress)
+        wait_timeout=wait_timeout, progress=progress)
     telemetry = extras.get("telemetry") or {}
     slo = (extras.get("freshness") or {}).get("slo") or {}
     return {
@@ -310,12 +289,9 @@ def run_sustained_cell(
         "ever_bound": stats.ever_bound,
         "lost": stats.lost,
         "p99_arrival_to_bind_ms": round(stats.latency_p99_ms()),
-        "overlap_share": telemetry.get("overlap_share", 0.0),
-        "overlapped_cycles": telemetry.get("overlapped_cycles", 0),
         "staleness_verdict": slo.get("snapshot_staleness"),
         "max_staleness_s": telemetry.get("max_staleness_s"),
         "encode_share": telemetry.get("encode_share", 0.0),
-        "pipeline": extras.get("pipeline"),
         "mirror": extras.get("mirror"),
         "session": extras.get("session"),
     }

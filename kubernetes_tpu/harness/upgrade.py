@@ -432,10 +432,8 @@ class _ReplicaFleet:
                           f"({handoff_ms:.0f}ms handoff)")
         return {"replica": j, "handoff_ms": round(handoff_ms, 1)}
 
-    def flush(self, timeout: float = 30.0) -> None:
-        for sched, bs in zip(self.replicas, self.batch_schedulers):
-            if bs is not None:
-                bs.flush(timeout=timeout)
+    def wait_for_inflight_bindings(self, timeout: float = 30.0) -> None:
+        for sched in self.replicas:
             sched.wait_for_inflight_bindings(timeout=timeout)
 
     def stop(self) -> None:
@@ -719,7 +717,7 @@ def run_upgrade_roll(
                 last_note = time.monotonic()
                 progress(f"upgrade[{scenario}]: {bound}/{want} bound")
             time.sleep(0.1)
-        rfleet.flush()
+        rfleet.wait_for_inflight_bindings()
         stats = engine.finish()
         engine = None
         time.sleep(0.5)   # quiesce: streams catch up before the audit
@@ -1178,7 +1176,7 @@ def run_upgrade_mini_cell(
             time.sleep(0.1)
         stop.set()
         t.join(timeout=5.0)
-        rfleet.flush(timeout=15.0)
+        rfleet.wait_for_inflight_bindings(timeout=15.0)
         time.sleep(settle_s)   # quiesce: informer catches up
 
         union: Dict[tuple, str] = {}

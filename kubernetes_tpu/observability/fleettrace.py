@@ -72,8 +72,7 @@ def phase_of(name: str) -> Optional[str]:
         return "encode"
     if name.startswith("solve") or name == "sidecar.serial":
         return "solve"
-    if name.startswith("queue") or name in ("sidecar.drain",
-                                            "sidecar.discard"):
+    if name.startswith("queue") or name == "sidecar.drain":
         return "queue"
     if name.startswith("rest") or name.startswith("route"):
         return "rest"

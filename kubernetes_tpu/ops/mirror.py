@@ -158,7 +158,6 @@ class DeviceClusterMirror:
             "scatter_mb": round(self.scatter_bytes_total / 1e6, 3),
             "reseeds": self.reseeds,
             "reseed_reasons": dict(self.reseed_reasons),
-            "carry_restores": self._session.carry_restores,
         }
 
     def _refuse(self, reason: str) -> None:

@@ -253,14 +253,6 @@ class SolverSession:
         self._node_epoch: int = -1
         self._poisoned = False
         self._warming = False
-        # materializer for the LAST lazy solve's handle (None when the
-        # result was returned eagerly, e.g. the rebuild path)
-        self.last_materializer = None
-        # (encoding, state) the LAST solve's dispatch consumed, handed
-        # out with its handle: a batch thrown away before its commit
-        # takes its placements back out of the carry with it
-        # (``restore_carry``)
-        self.last_carry = None
         # newest-applied-event anchor the LAST staleness sample was
         # taken against: a retry cycle solving an UNCHANGED snapshot
         # accrues no new staleness debt and must not be sampled (a
@@ -272,20 +264,6 @@ class SolverSession:
         self.incremental_hits = 0
         self.rebuilds = 0
         self.state_only_rebuilds = 0
-        # pipeline stage handoff: an incremental solve dispatched while
-        # the PREVIOUS lazy solve's handle was still unmaterialized
-        # chained directly onto its in-flight state carry — the device
-        # runs back-to-back batches with zero host round trip, and (on
-        # the donating mesh tier) the carry consumed by solve N is
-        # NEVER re-encoded or re-uploaded for N+1: XLA aliases it
-        # straight into N+1's inputs. ``carry_chained`` counts those
-        # dispatches; the differential guard and the sustained-arrival
-        # cell read it to prove the pipeline actually pipelines.
-        self.carry_chained = 0
-        # carries put back for discarded batches (``restore_carry``)
-        self.carry_restores = 0
-        self._dispatch_seq = 0      # lazy handles handed out
-        self._materialize_seq = 0   # lazy handles consumed
         # scheduling-cycle id stamped by the sidecar before each solve so
         # the per-cycle phase spans correlate with the pods' queue cycles
         self.trace_cycle = -1
@@ -319,11 +297,11 @@ class SolverSession:
     # ------------------------------------------------------------------
     def warm_pad(self, pods: List, pad: int) -> Optional[int]:
         """Compile the ``pad``-sized executable WITHOUT touching the
-        state mirror: runs one solve against the resident static/state
-        arrays and discards every output (jax arrays are immutable, so
-        the live ``self._state`` is untouched and any pipelined lazy
-        handle stays valid). The sidecar calls this between cycles when
-        the latency tuner shrinks to a bucket that has never compiled —
+        state mirror: runs one solve against the resident static and a
+        spare of the resident state (``_spare``) and discards every
+        output, so the live ``self._state`` is untouched. The sidecar
+        calls this between cycles when the latency tuner shrinks to a
+        bucket that has never compiled —
         the compile must burn an un-measured moment, not a real batch's
         e2e latency. Returns the number of compile events devprof
         MEASURED during the warm (0 = the executable was already cached
@@ -381,23 +359,6 @@ class SolverSession:
         clone = getattr(backend, "warm_state", None)
         return state if clone is None else clone(state)
 
-    def restore_carry(self, kept) -> bool:
-        """Put back the carry a discarded batch's solve consumed (its
-        ``last_carry``), so the batch's placements leave the device
-        state and the next catch-up scatters onto the cluster the host
-        cache holds. ``_last_seq`` stays: the kept carry is the state it
-        anchors. With no kept carry, or one from another encoding, the
-        session is invalidated instead and the next solve reseeds.
-        Returns whether the carry was restored."""
-        # the discarded handle is never materialized
-        self._materialize_seq = self._dispatch_seq
-        if kept is None or kept[0] is not self._cluster:
-            self.invalidate()
-            return False
-        self._state = kept[1]
-        self.carry_restores += 1
-        return True
-
     def invalidate(self) -> None:
         """Mark the device mirror diverged. Sticky until the next rebuild:
         a later ``note_committed`` must not re-validate (e.g. a host-
@@ -421,8 +382,8 @@ class SolverSession:
     def mirror_current(self) -> bool:
         """True when the device mirror is still consistent with the host
         cache RIGHT NOW (no unsanctioned mutations since it was last
-        validated). The pipelined sidecar checks this before committing
-        a batch solved one cycle earlier."""
+        validated): the next solve needs neither a catch-up nor a
+        rebuild."""
         return (
             not self._poisoned
             and self._last_seq == self.sched.cache.mutation_seq
@@ -459,20 +420,14 @@ class SolverSession:
             self._last_seq = -1
 
     # ------------------------------------------------------------------
-    def solve(self, pods: List, warming: bool = False, lazy: bool = False,
-              incremental_only: bool = False, pad_to: Optional[int] = None,
-              ) -> Optional[Tuple[object, EncodedCluster, int]]:
+    def solve(self, pods: List, warming: bool = False,
+              pad_to: Optional[int] = None,
+              ) -> Tuple[np.ndarray, EncodedCluster, int]:
         """Solve one batch. Returns (assignments, cluster, seq_before)
-        where assignments map batch index → node index in
-        ``cluster.node_names`` (-1 = unschedulable on device).
+        where assignments, a host array, map batch index → node index
+        in ``cluster.node_names`` (-1 = unschedulable on device).
         ``warming`` suppresses telemetry (metrics segments, rebuild
         counters) so JIT-compile time stays out of the measured series.
-        With ``lazy`` the assignments are an opaque handle — pass it to
-        ``materialize`` (captured via ``last_materializer``) later, so
-        host work overlaps the asynchronously-dispatched device solve.
-        With ``incremental_only`` the call returns None instead of
-        rebuilding (the pipelined caller must commit its in-flight batch
-        before a rebuild, or the fresh snapshot would miss it).
         ``pad_to`` overrides the padded batch shape (the sidecar's
         latency-budget chunking: the scan length — and so the per-batch
         device latency — is the PADDED size, not the real pod count;
@@ -547,23 +502,12 @@ class SolverSession:
                     # builds, the stage the device mirror eliminates.
                     dp.phase("pack", t_done - t0)
                     dp.add_bytes("h2d", ints.nbytes + floats.nbytes)
-                    # stage handoff: with the previous lazy handle
-                    # still in flight, this dispatch chains onto its
-                    # UNMATERIALIZED state carry — jax sequences the
-                    # two solves on device with no host sync, and a
-                    # donating backend aliases the consumed carry into
-                    # this solve's inputs (never re-encoded host-side)
-                    chained = self._dispatch_seq > self._materialize_seq
-                    self.last_carry = (self._cluster, self._spare(
-                        self._active, self._state))
                     t0 = time.monotonic()
                     with self._span("solve.device"):
                         handle, self._state = self._active.solve_lazy(
                             self.params, self._static, self._state,
                             ints, floats
                         )
-                    if chained and not warming:
-                        self.carry_chained += 1
                     staging = self._take_staging_s()
                     dp.phase("dispatch",
                              max(0.0, time.monotonic() - t0 - staging))
@@ -573,22 +517,16 @@ class SolverSession:
                         # this copy — device wait, not dispatch work
                         dp.phase("block", staging)
                     self._flush_backend_bytes(dp)
-                    if lazy:
-                        self.last_materializer = \
-                            self._timed_materializer(rec)
-                    else:
-                        t_b = time.monotonic()
-                        with self._span("solve.block"):
-                            handle = self._active.materialize(handle)
-                        dp.phase("block", time.monotonic() - t_b)
-                        dp.add_bytes(
-                            "d2h", int(getattr(handle, "nbytes", 0)))
-                        self.last_materializer = None
+                    t_b = time.monotonic()
+                    with self._span("solve.block"):
+                        out = self._active.materialize(handle)
+                    dp.phase("block", time.monotonic() - t_b)
+                    dp.add_bytes("d2h", int(getattr(out, "nbytes", 0)))
                     self._observe("device", time.monotonic() - t0)
-                    dp.end_cycle(rec, pending_block=lazy)
+                    dp.end_cycle(rec)
                     if not self._warming:
                         self.incremental_hits += 1
-                    return handle, self._cluster, seq_before
+                    return out, self._cluster, seq_before
                 # incremental encode fell through (epoch shape drift):
                 # the record describes no solve — drop it rather than
                 # pollute the cycle stream with an empty row
@@ -601,10 +539,6 @@ class SolverSession:
                 # later compile events to a dead cycle
                 dp.abort(rec)
                 raise
-        if incremental_only:
-            return None
-        # the rebuild path always solves eagerly (rebuilds are rare and
-        # the caller just committed any in-flight batch anyway)
         return self._rebuild_and_solve(
             pods, seq_before, pad, reason or self._rebuild_reason())
 
@@ -703,43 +637,6 @@ class SolverSession:
         except Exception:  # noqa: BLE001 — accounting must never break
             return 0.0
 
-    def _timed_materializer(self, rec):
-        """Wrap the backend's materialize so a lazy solve's
-        ``block_until_ready`` wait — which lands cycles later, inside
-        the commit pipeline — is measured and attributed to the cycle
-        that dispatched it (devprof ``note_block`` completes the record;
-        a ``solve.block`` tracer span carries the same cycle id so
-        ``/debug/trace`` shows the wait next to the dispatch). The
-        wrapper also advances the dispatch/materialize sequence the
-        ``carry_chained`` stage-handoff counter reads, so it is
-        returned even with devprof off (``rec`` None — ``note_block``
-        then no-ops); the residual cost is one closure per CYCLE."""
-        mat = self._active.materialize
-        self._dispatch_seq += 1
-        token = self._dispatch_seq
-        dp = get_devprof()
-        cycle = self.trace_cycle
-
-        def _timed(handle):
-            with get_tracer().span("solve.block", cycle=cycle):
-                t0 = time.monotonic()
-                out = mat(handle)
-                end = time.monotonic()
-            if token > self._materialize_seq:
-                self._materialize_seq = token
-            try:
-                # start_mono lets devprof compute overlap_s: the host
-                # work performed between dispatch and this block is the
-                # time the pipeline hid under the in-flight solve
-                dp.note_block(rec, end - t0,
-                              int(getattr(out, "nbytes", 0)),
-                              start_mono=t0)
-            except Exception:  # noqa: BLE001 — must never break commits
-                pass
-            return out
-
-        return _timed
-
     # inputs whose equality makes the packed STATIC planes bit-identical
     _STATIC_FP_CLUSTER = ("allocatable", "max_pods", "topo_codes")
     _STATIC_FP_BATCH = (
@@ -776,11 +673,6 @@ class SolverSession:
         if not self._warming:
             self.rebuilds += 1
         self._poisoned = False
-        # a pending handle the sidecar discarded (mirror drift) is
-        # never materialized; re-sync the stage-handoff sequence so the
-        # dangling token can't make every later dispatch read as
-        # chained onto a carry that no longer exists
-        self._materialize_seq = self._dispatch_seq
         dp = get_devprof()
         rec = dp.begin_cycle(
             cycle=self.trace_cycle, pad=pad or self.max_batch,
@@ -870,7 +762,6 @@ class SolverSession:
                 else:
                     self._static_fp = None
                     self._static, state = backend.prepare(cluster, batch)
-        kept = (cluster, self._spare(backend, state))
         t_disp = time.monotonic()
         with self._span("solve.device"):
             handle, self._state = backend.solve_lazy(
@@ -894,8 +785,6 @@ class SolverSession:
         dp.add_bytes("d2h", int(getattr(out, "nbytes", 0)))
         self._active = backend
         self._static_fp = fp
-        self.last_materializer = None  # already materialized
-        self.last_carry = kept
         if state_only and not self._warming:
             self.state_only_rebuilds += 1
         self._observe("device", t_end - t0)

@@ -136,12 +136,8 @@ class SchedulerReplicaSet:
             total += s[2] if s else 0
         return total
 
-    def flush(self, timeout: float = 30.0) -> None:
-        for sched, bs in zip(self.replicas,
-                             self.batch_schedulers or
-                             [None] * len(self.replicas)):
-            if bs is not None:
-                bs.flush(timeout=timeout)
+    def wait_for_inflight_bindings(self, timeout: float = 30.0) -> None:
+        for sched in self.replicas:
             sched.wait_for_inflight_bindings(timeout=timeout)
 
     def pending_count(self) -> int:

@@ -8,21 +8,18 @@ through the framework's assume → Reserve → Permit → Bind pipeline so every
 host-side contract (cache assume/TTL, volume reservations, gang permits,
 events, metrics) is preserved.
 
-The solve loop is a STREAMING PIPELINE (double-buffered, Pathways-style
-host/device overlap): per pump cycle the host drains batch N+1 under a
-non-blocking queue hint, encodes its delta columns against the live
-snapshot, and dispatches its solve — jax dispatch is async, so the
-dispatch chains onto batch N's in-flight state carry with no host
-sync — then commits batch N−1 while the device crunches, with remote
-clients' bulk binds flying on the binding pool (batch N−2 may still be
-on the wire). Every correctness guard runs in its original stage:
-stale-node probes and ``commit_fits`` at commit time, drift re-encode
-via ``mirror_current``/``note_drift``, the mutation-ledger arithmetic
-per cycle. ``KTPU_PIPELINE=off`` is the kill-switch: the exact
-serialized barrier loop (drain → encode → solve → commit per call),
-held bit-identical to the pipeline by the differential guard in
-tests/test_pipeline.py. ``devprof`` measures what the overlap wins as
-``overlap_share`` (the ``pipeline[...]`` diag segment).
+Each ``run_batch`` call is one cycle: drain the queue, solve the batch
+against the device-resident state (the session first scatters or
+rebuilds whatever changed since the last solve), and commit every
+assignment before it returns; remote clients' bulk binds may still be
+on the binding pool. Nothing solved is held across calls, so every
+commit-time guard (stale-node probes, ``commit_fits``, drift re-encode
+via ``note_drift``, the per-cycle mutation-ledger arithmetic) judges a
+solve against the cache it was made on. There is no stage that
+overlaps host work with the device solve: on a TPU v5e the device is
+idle for more than 98% of every benchmark cell's window, so a batch
+held for overlap hides nothing, and the churn between calls would make
+it stale before its commit.
 
 Fallback contract (mirrors how extenders are ``IsIgnorable``,
 ``core/extender.go:154``; SURVEY.md section 5): any pod the tensor model
@@ -44,7 +41,6 @@ Enable with::
 from __future__ import annotations
 
 import logging
-import os
 import time
 from typing import Dict, List, Optional
 
@@ -57,7 +53,7 @@ from kubernetes_tpu.ops.solver import SolverParams
 from kubernetes_tpu.scheduler.core import ScheduleResult
 from kubernetes_tpu.scheduler.framework.cycle_state import CycleState
 from kubernetes_tpu.scheduler.scheduler import Scheduler, commit_target_stale
-from kubernetes_tpu.scheduler.types import PodInfo, QueuedPodInfo
+from kubernetes_tpu.scheduler.types import QueuedPodInfo
 
 
 class _CommitVolumeBinder:
@@ -171,24 +167,9 @@ class TPUBatchScheduler:
         validate: bool = False,
         backend=None,
         adaptive_chunk: bool = True,
-        pipeline: Optional[bool] = None,
     ):
         self.sched = scheduler
         self.max_batch = max_batch
-        # streaming pipeline kill-switch: ``KTPU_PIPELINE=off`` (or
-        # pipeline=False) runs the serialized barrier loop — drain →
-        # encode → solve (eager) → commit in ONE call, nothing carried
-        # across cycles. The differential guard
-        # (tests/test_pipeline.py) asserts a bit-identical bound set
-        # between the two arms over identical seeded event sequences.
-        if pipeline is None:
-            pipeline = os.environ.get(
-                "KTPU_PIPELINE", "").lower() not in ("off", "0", "false")
-        self.pipeline_enabled = bool(pipeline)
-        # max batches simultaneously in flight across the stages
-        # (solve N dispatched + commit N−1 pending + N−2's bulk binds
-        # on the binding pool) — the ``pipeline[depth=...]`` diag
-        self.pipeline_depth_max = 0
         # False pins the drain/pad size at max_batch (no latency-budget
         # tuning): the multi-chip scaling bench needs every mesh size to
         # solve the IDENTICAL batch partition, or slower configurations
@@ -204,14 +185,11 @@ class TPUBatchScheduler:
         # multi-chip ShardedBackend over a device mesh).
         self.session = SolverSession(scheduler, params=params,
                                      max_batch=max_batch, backend=backend)
-        # one solved-but-uncommitted batch (pipelining: the host commits
-        # batch k while the device solves batch k+1)
-        self._pending: Optional[dict] = None
         # latency-budget chunking: drain/pad size, halved (power-of-2
         # buckets — each bucket is one compiled executable) whenever a
         # batch cycle overruns the budget. Wide-term workloads that
         # solve slowly get small low-latency batches; fast ones keep
-        # the full pipeline width.
+        # the full batch width.
         self._chunk = max_batch
         # pad sizes whose executables are known-compiled. A tuner shrink
         # to an UNWARMED bucket must never compile inside a measured
@@ -237,9 +215,6 @@ class TPUBatchScheduler:
         # healthy device)
         self.batch_committed_pods = 0
         self.serial_fallback_pods = 0
-        # pods of solved batches thrown away because the mirror drifted
-        # before their commit (each is solved again the same cycle)
-        self.discarded_pods = 0
         # cache mutations the CURRENT cycle's commits performed
         # (accumulated from commit_assignments_bulk's ledger): the
         # session's validity arithmetic must count every sanctioned
@@ -291,38 +266,76 @@ class TPUBatchScheduler:
             self._need_warm_pad = self._chunk
 
     def run_batch(self, pop_timeout: Optional[float] = 0.2) -> int:
-        """One pump cycle. Default (``KTPU_PIPELINE`` unset): the
-        STREAMING pipeline — drain batch N+1 under a non-blocking
-        hint, encode its delta columns and dispatch its solve (jax
-        dispatch is async, chaining onto batch N's in-flight state
-        carry), then commit batch N−1 while the device crunches, its
-        bulk binds flying on the binding pool for remote clients. A
-        solved batch is held at most one cycle and commits immediately
-        when the queue is empty, so single-shot callers see their pods
-        bound in the same call. With ``KTPU_PIPELINE=off``: the
-        serialized barrier loop (one batch per call, solve blocks,
-        commit follows — the differential guard's reference arm).
-        Returns the number of pods worked on this cycle."""
-        if not self.pipeline_enabled:
-            return self._run_batch_serialized(pop_timeout)
-        return self._run_batch_pipelined(pop_timeout)
+        """One pump cycle: drain up to one chunk of pods, solve the
+        batchable ones, commit every assignment and run the rest on
+        the serial path, all before returning. Returns the number of
+        pods worked on this cycle."""
+        if self.sched.is_degraded():
+            # circuit open: the batch path pauses exactly like the
+            # serial loop, and drains nothing until recovery
+            time.sleep(min(pop_timeout, 0.05) if pop_timeout else 0.01)
+            return 0
+        with get_tracer().span("queue.cycle") as cycle:
+            return self._pass(pop_timeout, cycle)
 
-    # -- shared stages --------------------------------------------------
-    def _degraded_pause(self, pop_timeout: Optional[float]) -> None:
-        # circuit open: the batch path pauses exactly like the
-        # serial loop — solved-but-uncommitted work stays pending
-        # and commits on the first cycle after recovery. Always
-        # sleep: flush() drives this with pop_timeout=0.0 in a
-        # while-_pending loop, which must not become a busy spin.
-        time.sleep(min(pop_timeout, 0.05) if pop_timeout else 0.01)
+    def _pass(self, pop_timeout: Optional[float], cycle) -> int:
+        sched = self.sched
+        self.session.cycle_rebuild = "none"
+        self._service_warm_pad()
+        with get_tracer().span("sidecar.drain") as drain:
+            qpis = self._drain(pop_timeout)
+            batchable, serial = self._partition(qpis)
+            if not qpis:
+                drain.drop()
+        committed = 0
+        self._cycle_mutations = 0
+        seq_anchor = sched.cache.mutation_seq
+        if batchable:
+            # pad sized from the PARTITIONED batchable count: a bucket
+            # sized for serial-fallback pods too is a larger device scan
+            pad = self._select_pad(len(batchable))
+            # correlate this batch's solver phase spans with its pods'
+            # scheduling cycles (the flight recorder's cycle id)
+            self.session.trace_cycle = batchable[0][1]
+            start = time.monotonic()
+            try:
+                assignments, cluster, _ = self.session.solve(
+                    [q.pod for q, _ in batchable], pad_to=pad)
+                # this pad's executable is live now, and these pods are
+                # shape-representative for future pre-warms
+                self._warmed_pads.add(pad)
+                self._warm_samples = [q.pod for q, _ in batchable[:8]]
+                committed += self._commit_pending_safe({
+                    "batchable": batchable,
+                    "assignments": assignments,
+                    "cluster": cluster,
+                    "profiles": self.session.last_profile_idx,
+                    "inexpressible": self.session.last_inexpressible,
+                    "masks": self.session.static_masks_host,
+                    "start": start,
+                    "pad": pad,
+                }, serial)
+            except Exception:  # noqa: BLE001 — popped pods must not be lost
+                self._rescue("solve", batchable, serial)
+        self._run_serial(serial)
+        # session validity: every cache mutation since the anchor must
+        # be one this cycle's commits performed (assumes — including
+        # gang pods parked at Permit — plus sync rejection forgets,
+        # commit_assignments_bulk's ledger). Serial binds, async-bind
+        # failures, or external events show up as extra mutations; with
+        # the device mirror attached they land in the delta journal and
+        # the next solve scatters them into the resident planes, without
+        # it they invalidate the session for a full rebuild.
+        self.session.note_committed(self._cycle_mutations, seq_anchor)
+        self._close_cycle(cycle, len(qpis), committed)
+        return len(qpis)
 
+    # -- stages ---------------------------------------------------------
     def _service_warm_pad(self) -> None:
         if self._need_warm_pad is None:
             return
-        # session.warm_pad discards its outputs, so the resident
-        # state — and any pipelined batch's lazy handle — survive;
-        # this runs on the very next cycle after a shrink, even
-        # under sustained load where something is always in flight
+        # session.warm_pad discards its outputs, so the resident state
+        # survives; this runs on the very next cycle after a shrink
         pad = self._need_warm_pad
         self._need_warm_pad = None
         if pad not in self._warmed_pads and self._warm_samples:
@@ -336,7 +349,7 @@ class TPUBatchScheduler:
 
     def _partition(self, qpis: List[tuple]):
         """Batchable vs serial-fallback split (one wfc-class scan per
-        drain, not one per pod) — identical in both pipeline arms."""
+        drain, not one per pod)."""
         sched = self.sched
         batchable: List[tuple] = []
         serial: List[QueuedPodInfo] = []
@@ -368,258 +381,21 @@ class TPUBatchScheduler:
                 return b
         return pad
 
-    # -- the pipelined loop ---------------------------------------------
-    def _close_cycle(self, cycle, processed: int, committed: int,
-                     discarded: int) -> None:
+    def _close_cycle(self, cycle, processed: int, committed: int) -> None:
         """Count one pass and finish its batch-level ``queue.cycle``
         span (drain → solve → commit). The span carries no pod trace,
         so critical-path attribution overlays it at the LOWEST
         priority: it soaks up the per-pod assembly gaps no specific span
-        covers (pop → solve dispatch, solve handle → pending stamp,
-        guard re-probes between commit chunks) without ever masking
-        encode/solve/commit/bind time. Idle passes (nothing drained,
-        committed or discarded) leave no record."""
+        covers (pop → solve dispatch, solve → commit, guard re-probes
+        between commit chunks) without ever masking
+        encode/solve/commit/bind time. Idle passes (nothing drained)
+        leave no record."""
         self.batch_committed_pods += committed
-        self.discarded_pods += discarded
-        if processed == 0 and committed == 0 and discarded == 0:
+        if processed == 0:
             cycle.drop()
             return
-        cycle.set(pods=processed, committed=committed, discarded=discarded,
+        cycle.set(pods=processed, committed=committed,
                   rebuild=self.session.cycle_rebuild)
-
-    def _run_batch_pipelined(self, pop_timeout: Optional[float]) -> int:
-        if self.sched.is_degraded():
-            self._degraded_pause(pop_timeout)
-            return 0
-        with get_tracer().span("queue.cycle") as cycle:
-            return self._pipelined_pass(pop_timeout, cycle)
-
-    def _pipelined_pass(self, pop_timeout: Optional[float], cycle) -> int:
-        sched = self.sched
-        tracer = get_tracer()
-        self.session.cycle_rebuild = "none"
-        prev = self._pending
-        self._pending = None
-        self._service_warm_pad()
-
-        # a pending batch solved against a mirror that has since
-        # diverged (external events, failed commits) is suspect: its
-        # assignments are discarded and its pods RE-SOLVED this cycle
-        # (the solve below rebuilds from a fresh snapshot), keeping
-        # them on the batch path instead of serializing up to
-        # max_batch pods. Carried-over pods go back through the SAME
-        # partition as freshly drained ones, against the live store
-        # object — the divergence that discarded the batch may be the
-        # pod itself being deleted or updated (e.g. gaining a PVC)
-        # while its batch was in flight. The discarded solve's
-        # placements leave the device carry with it: the session puts
-        # back the carry that solve consumed, so the re-solve's
-        # catch-up scatters the new events onto the cluster as the host
-        # cache holds it (or, with no carry kept, reseeds).
-        discarded = 0
-        qpis = None
-        if prev is not None and not self.session.mirror_current():
-            discarded = len(prev["batchable"])
-            with tracer.span("sidecar.discard", pods=discarded) as span:
-                qpis = []
-                for qpi, qcycle in prev["batchable"]:
-                    pod = qpi.pod
-                    live = sched.client.get_pod(pod.namespace, pod.name)
-                    if live is None or live.uid != pod.uid:
-                        continue  # deleted (and maybe recreated) in flight
-                    if live is not pod:
-                        qpi.pod_info = PodInfo.of(live)
-                    qpis.append((qpi, qcycle))
-                restored = self.session.restore_carry(prev.get("carry"))
-                span.set(restored=discarded if restored else 0,
-                         carry="restored" if restored else "invalidated")
-            prev = None
-        with tracer.span("sidecar.drain") as drain:
-            if qpis is None:
-                # drain stage, hint-gated: the non-blocking peek decides
-                # whether a drain is worth attempting at all — with
-                # batch N−1's commit pending and nothing queued, skip
-                # the pop (and its condition wait) entirely so stage
-                # overlap never parks on an empty queue; with work
-                # queued, drain without waiting. Only a fully idle
-                # pipeline blocks for pop_timeout (the pump loops'
-                # idle-wait contract).
-                hint_n, _hint_prio = sched.queue.pending_hint()
-                if prev is not None and hint_n == 0:
-                    qpis = []
-                else:
-                    qpis = self._drain(
-                        0.0 if (prev is not None or hint_n)
-                        else pop_timeout)
-            batchable, serial = self._partition(qpis)
-            if not qpis:
-                drain.drop()
-        processed = len(qpis)
-
-        committed = 0
-        self._cycle_mutations = 0
-        seq_anchor = sched.cache.mutation_seq
-        if batchable:
-            # pad sized from the PARTITIONED batchable count — the raw
-            # hint overstates it whenever serial-fallback pods rode the
-            # drain, and an overstated bucket is a larger device scan
-            pad = self._select_pad(len(batchable))
-            # correlate this batch's solver phase spans with its pods'
-            # scheduling cycles (the flight recorder's cycle id)
-            self.session.trace_cycle = batchable[0][1]
-            try:
-                res = self.session.solve(
-                    [q.pod for q, _ in batchable], lazy=True,
-                    incremental_only=prev is not None,
-                    pad_to=pad,
-                )
-                if res is None:
-                    # this solve needs a full rebuild, whose snapshot
-                    # must include the in-flight batch: commit it first
-                    # and settle the mutation accounting BEFORE the
-                    # rebuild re-anchors the mirror (no overlap this
-                    # cycle — rebuilds are rare)
-                    committed += self._commit_pending_safe(prev, serial)
-                    self.session.note_committed(self._cycle_mutations,
-                                                seq_anchor)
-                    self._cycle_mutations = 0
-                    processed += len(prev["batchable"])
-                    prev = None
-                    seq_anchor = sched.cache.mutation_seq
-                    res = self.session.solve(
-                        [q.pod for q, _ in batchable], lazy=True,
-                        pad_to=pad,
-                    )
-                handle, cluster, _ = res
-                # this pad's executable is live now, and these pods are
-                # shape-representative for future pre-warms
-                self._warmed_pads.add(pad)
-                self._warm_samples = [q.pod for q, _ in batchable[:8]]
-                self._pending = {
-                    "batchable": batchable,
-                    "handle": handle,
-                    "materializer": self.session.last_materializer,
-                    "carry": self.session.last_carry,
-                    "cluster": cluster,
-                    "profiles": self.session.last_profile_idx,
-                    "inexpressible": self.session.last_inexpressible,
-                    # static masks for THIS batch's profiles — the
-                    # session's live fields may describe a newer batch
-                    # by the time this one commits
-                    "masks": self.session.static_masks_host,
-                    "start": time.monotonic(),
-                    "pad": pad,
-                }
-                # pipeline depth at this instant: solve N in flight,
-                # batch N−1 solved-but-uncommitted, batch N−2's bulk
-                # binds still on the binding pool
-                depth = 1 + (1 if prev is not None else 0) + (
-                    1 if getattr(sched, "_inflight_bindings", 0) else 0)
-                if depth > self.pipeline_depth_max:
-                    self.pipeline_depth_max = depth
-            except Exception:  # noqa: BLE001 — popped pods must not be lost
-                self._rescue("solve", batchable, serial)
-
-        # commit the previous cycle's batch while the device solves
-        # (every guard — stale-node probes, commit_fits, drift
-        # re-encode — runs inside _commit_pending, stage-unchanged)
-        if prev is not None:
-            committed += self._commit_pending_safe(prev, serial)
-            processed += len(prev["batchable"])
-
-        # nothing else queued: no overlap to win — commit the fresh
-        # solve in the same call (also the single-shot caller contract)
-        if self._pending is not None and sched.queue.num_active() == 0:
-            pending = self._pending
-            self._pending = None
-            committed += self._commit_pending_safe(pending, serial)
-
-        self._run_serial(serial)
-        # session validity: every cache mutation since the anchor must
-        # be one this cycle's commits performed (assumes — including
-        # gang pods parked at Permit — plus sync rejection forgets,
-        # commit_assignments_bulk's ledger). Serial binds, async-bind
-        # failures, or external events show up as extra mutations; with
-        # the device mirror attached they land in the delta journal and
-        # the next solve scatters them into the resident planes, without
-        # it they invalidate the session for a full rebuild.
-        self.session.note_committed(self._cycle_mutations, seq_anchor)
-        self._close_cycle(cycle, processed, committed, discarded)
-        return processed
-
-    # -- the serialized (kill-switch) loop ------------------------------
-    def _run_batch_serialized(self, pop_timeout: Optional[float]) -> int:
-        """The ``KTPU_PIPELINE=off`` barrier loop: drain → encode →
-        solve (eager — the materializer blocks inside the solve) →
-        commit, one batch per call, nothing carried across cycles.
-        Every guard runs exactly as in the pipelined arm (same
-        ``_commit_pending``); only the overlap is gone. This is the
-        differential guard's reference arm and the operational
-        kill-switch if the pipeline ever misbehaves in production."""
-        if self.sched.is_degraded():
-            self._degraded_pause(pop_timeout)
-            return 0
-        with get_tracer().span("queue.cycle") as cycle:
-            return self._serialized_pass(pop_timeout, cycle)
-
-    def _serialized_pass(self, pop_timeout: Optional[float], cycle) -> int:
-        sched = self.sched
-        self.session.cycle_rebuild = "none"
-        self._service_warm_pad()
-        with get_tracer().span("sidecar.drain") as drain:
-            qpis = self._drain(pop_timeout)
-            batchable, serial = self._partition(qpis)
-            if not qpis:
-                drain.drop()
-        processed = len(qpis)
-        committed = 0
-        self._cycle_mutations = 0
-        seq_anchor = sched.cache.mutation_seq
-        if batchable:
-            pad = self._select_pad(len(batchable))
-            self.session.trace_cycle = batchable[0][1]
-            start = time.monotonic()
-            try:
-                res = self.session.solve(
-                    [q.pod for q, _ in batchable], lazy=False,
-                    pad_to=pad,
-                )
-                handle, cluster, _ = res
-                self._warmed_pads.add(pad)
-                self._warm_samples = [q.pod for q, _ in batchable[:8]]
-                committed += self._commit_pending_safe({
-                    "batchable": batchable,
-                    "handle": handle,
-                    "materializer": None,   # already materialized
-                    "cluster": cluster,
-                    "profiles": self.session.last_profile_idx,
-                    "inexpressible": self.session.last_inexpressible,
-                    "masks": self.session.static_masks_host,
-                    "start": start,
-                    "pad": pad,
-                }, serial)
-            except Exception:  # noqa: BLE001 — popped pods must not be lost
-                self._rescue("solve", batchable, serial)
-        self._run_serial(serial)
-        self.session.note_committed(self._cycle_mutations, seq_anchor)
-        self._close_cycle(cycle, processed, committed, 0)
-        return processed
-
-    def pipeline_info(self, telemetry: Optional[Dict] = None
-                      ) -> Optional[Dict]:
-        """The ``pipeline[...]`` diag segment's payload: max observed
-        stage depth plus (when a devprof summary is supplied) the
-        overlap share and how many cycles actually overlapped. None
-        when the pipeline is off OR never dispatched a batch (a
-        serial-only or empty row) — those rows print nothing, the
-        quiet-row convention the other diag segments follow."""
-        if not self.pipeline_enabled or self.pipeline_depth_max == 0:
-            return None
-        info: Dict = {"depth": self.pipeline_depth_max}
-        if telemetry:
-            info["overlap"] = float(telemetry.get("overlap_share", 0.0))
-            info["cycles"] = int(telemetry.get("overlapped_cycles", 0))
-        return info
 
     def mirror_info(self, telemetry: Optional[Dict] = None
                     ) -> Optional[Dict]:
@@ -629,7 +405,7 @@ class TPUBatchScheduler:
         mirror had to fall back to a full reseed, and (when a devprof
         summary is supplied) the surviving encode share. None when the
         mirror is off (``KTPU_MIRROR=off`` or a backend without scatter
-        hooks) — quiet-row convention, same as ``pipeline_info``."""
+        hooks): quiet rows print no segment."""
         mirror = getattr(self.session, "_mirror", None)
         if mirror is None:
             return None
@@ -637,19 +413,6 @@ class TPUBatchScheduler:
         if telemetry and "encode_share" in telemetry:
             info["encode_share"] = float(telemetry["encode_share"])
         return info
-
-    def flush(self, timeout: float = 60.0) -> int:
-        """Commit any held solved-but-uncommitted batch (the pipelining
-        tail): a run that stops pumping mid-stream must not strand popped
-        pods in ``_pending``. Returns the number of pods processed.
-        Bounded by ``timeout``: in degraded mode the commit is paused,
-        and a shutdown-path flush must not wait forever on a server
-        that may never come back."""
-        total = 0
-        deadline = time.monotonic() + timeout
-        while self._pending is not None and time.monotonic() < deadline:
-            total += self.run_batch(pop_timeout=0.0)
-        return total
 
     def _run_serial(self, serial: List[QueuedPodInfo]) -> None:
         if not serial:
@@ -769,8 +532,7 @@ class TPUBatchScheduler:
 
     def _commit_pending_safe(self, pending: dict,
                              serial: List[QueuedPodInfo]) -> int:
-        """_commit_pending, but a failure (e.g. an async device error
-        surfacing at materialization) must not lose popped pods: they
+        """_commit_pending, but a failure must not lose popped pods: they
         fall back to the serial path (already-assumed ones are skipped
         there by skip_pod_schedule)."""
         try:
@@ -796,16 +558,13 @@ class TPUBatchScheduler:
     # ------------------------------------------------------------------
     def _commit_pending(self, pending: dict,
                         serial: List[QueuedPodInfo]) -> int:
-        """Materialize and commit one solved batch. Returns the number
-        of successfully committed pods; declined/rejected pods are
-        appended to ``serial`` or failed directly (mass decline). The
-        ``solve.commit`` span starts after the materialize (its wait is
-        ``solve.block``)."""
+        """Commit one solved batch. Returns the number of successfully
+        committed pods; declined/rejected pods are appended to
+        ``serial`` or failed directly (mass decline)."""
         batchable = pending["batchable"]
         start = pending["start"]
         pad = pending.get("pad", self.max_batch)
-        mat = pending["materializer"] or (lambda h: h)
-        assignments = mat(pending["handle"])
+        assignments = pending["assignments"]
         with get_tracer().span(
                 "solve.commit", pods=len(batchable),
                 cycle=batchable[0][1] if batchable else -1,
@@ -1248,18 +1007,14 @@ def attach_batch_scheduler(
     validate: bool = False,
     backend=None,
     adaptive_chunk: bool = True,
-    pipeline: Optional[bool] = None,
 ) -> Optional[TPUBatchScheduler]:
     """Install the batch path iff the TPUBatchScheduler gate is enabled
-    (the --feature-gates=TPUBatchScheduler wiring). ``pipeline``
-    overrides the ``KTPU_PIPELINE`` kill-switch (None = read the env;
-    False = the serialized barrier loop)."""
+    (the --feature-gates=TPUBatchScheduler wiring)."""
     if not sched.feature_gates.enabled("TPUBatchScheduler"):
         return None
     bs = TPUBatchScheduler(sched, max_batch=max_batch, params=params,
                            validate=validate, backend=backend,
-                           adaptive_chunk=adaptive_chunk,
-                           pipeline=pipeline)
+                           adaptive_chunk=adaptive_chunk)
     sched.batch_scheduler = bs
     try:
         # the schedule-latency SLO reads the e2e histogram from THIS

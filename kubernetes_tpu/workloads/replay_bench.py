@@ -324,8 +324,6 @@ def run_replay_once(
         engine.start()
         _pump_to_quiesce(sched, bs, engine,
                          time.monotonic() + wait_timeout)
-        if bs is not None:
-            bs.flush()
         sched.wait_for_inflight_bindings(timeout=30.0)
         extras["wall_s"] = round(time.monotonic() - t0, 2)
         stats = engine.finish()

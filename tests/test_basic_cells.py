@@ -1,7 +1,8 @@
 """The ``basic-5k`` deployment's backlog and open-loop cells at a CPU
 test's size: the benchmark's own harness builds the deployment from the
 repository's files (``benchmark.tests.tiny``), drives the default path
-(pipelined sidecar, mirror on, the Pallas kernel in interpret mode) and
+(the sidecar's one loop, mirror on, the Pallas kernel in interpret
+mode) and
 replays every bind through the plain reference. Each run has to read
 ``correct`` true with every bind on a best-scoring node.
 """
@@ -17,7 +18,6 @@ def tree(tmp_path, monkeypatch):
 
     monkeypatch.setenv("KTPU_SOLVER", "pallas")
     monkeypatch.delenv("KTPU_MIRROR", raising=False)
-    monkeypatch.delenv("KTPU_PIPELINE", raising=False)
     # the harness tunes the collector for its window; put it back for
     # the tests that share this process
     threshold = gc.get_threshold()
@@ -37,8 +37,9 @@ def test_basic_cell_correct(tree, cell):
 
 
 def test_basic_backlog_restores_every_discard(tree):
-    """The traced backlog run: every discarded batch's carry is put
-    back, whole, and no cycle in the window rebuilds."""
+    """The traced backlog run: the client's churn deletes between calls
+    discard nothing; each cycle's solve catches them up (no cycle in
+    the window rebuilds) and commits every pod it drained."""
     from kubernetes_tpu.observability import get_tracer
 
     tiny, root = tree
@@ -48,6 +49,7 @@ def test_basic_backlog_restores_every_discard(tree):
     def spans(sched, bs):
         # the window opens next: keep its spans only
         keep["bs"] = bs
+        keep["catch_ups"] = bs.mirror_info()["catch_ups"]
         t.clear()
 
     saved = (t.enabled, t.sample_rate)
@@ -62,12 +64,15 @@ def test_basic_backlog_restores_every_discard(tree):
         t.clear()
     assert out["correct"] is True, out["checks"]
     assert "scatter_ms_per_cycle.backlog" in out["metrics"]
-    assert "discard_ms_per_cycle.backlog" in out["metrics"]
-    discards = [r[8] for r in recs if r[0] == "sidecar.discard"]
-    assert discards
-    for d in discards:
-        assert d["carry"] == "restored" and d["restored"] == d["pods"]
-    rebuilds = {r[8]["rebuild"] for r in recs if r[0] == "queue.cycle"}
-    assert rebuilds <= {"none", "scatter"}
+    assert out["metrics"]["discard_ms_per_cycle.backlog"]["value"] == 0.0
+    assert "discarded_pods_per_cycle.backlog" not in out["metrics"]
+    assert not [r for r in recs if r[0] == "sidecar.discard"]
+    cycles = [r[8] for r in recs if r[0] == "queue.cycle"]
+    assert cycles
+    for c in cycles:
+        assert c["committed"] == c["pods"], c
+    assert {c["rebuild"] for c in cycles} <= {"none", "scatter"}
+    assert "scatter" in {c["rebuild"] for c in cycles}
     info = keep["bs"].mirror_info()
-    assert info["carry_restores"] >= len(discards)
+    assert info["catch_ups"] > keep["catch_ups"]
+    assert "carry_restores" not in info

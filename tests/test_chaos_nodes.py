@@ -206,8 +206,8 @@ class TestSerialCommitGuard:
 class TestBatchPathStaleRouting:
     def test_node_death_between_solve_and_commit_routes_serial(self):
         """A batch solved against a snapshot containing a node that dies
-        mid-cycle (after the pipelined mirror check, before the commit)
-        must not bind there: the sidecar's guard routes the pod to the
+        mid-cycle (after the solve, before the commit) must not bind
+        there: the sidecar's guard routes the pod to the
         serial path, which places it on the surviving node, and the
         session is told the node planes drifted."""
         store = ClusterStore()
@@ -223,12 +223,11 @@ class TestBatchPathStaleRouting:
                 time.sleep(0.02)
             qpis = bs._drain(0.5)
             assert len(qpis) == 1
-            res = bs.session.solve([q.pod for q, _ in qpis], lazy=True)
-            handle, cluster, _seq = res
+            assignments, cluster, _seq = bs.session.solve(
+                [q.pod for q, _ in qpis])
             pending = {
                 "batchable": qpis,
-                "handle": handle,
-                "materializer": bs.session.last_materializer,
+                "assignments": assignments,
                 "cluster": cluster,
                 "profiles": bs.session.last_profile_idx,
                 "inexpressible": bs.session.last_inexpressible,
@@ -236,10 +235,6 @@ class TestBatchPathStaleRouting:
                 "start": time.monotonic(),
                 "pad": bs._chunk,
             }
-            mat = pending["materializer"] or (lambda h: h)
-            assignments = mat(pending["handle"])
-            pending["handle"] = assignments
-            pending["materializer"] = None
             target = cluster.node_names[int(assignments[0])]
             survivor = "n2" if target == "n1" else "n1"
             # the solved target dies mid-cycle, before the commit
@@ -279,7 +274,6 @@ class TestBatchPathStaleRouting:
             while time.time() < deadline and \
                     not store.get_pod("default", "p0").spec.node_name:
                 bs.run_batch(pop_timeout=0.05)
-            bs.flush()
             session = bs.session
             rebuilds_before = session.rebuilds
             # forge mutation-arithmetic agreement, but move the node set
@@ -288,12 +282,15 @@ class TestBatchPathStaleRouting:
             sched.cache.remove_node(store.get_node("n3"))
             session._last_seq = sched.cache.mutation_seq
             assert not session.mirror_current()
-            res = session.solve(
+            hits_before = session.incremental_hits
+            assignments, cluster, _seq = session.solve(
                 [MakePod().name("px").uid("ux")
-                 .req({"cpu": "100m"}).obj()],
-                incremental_only=True)
-            assert res is None   # refused: rebuild required
-            assert session.rebuilds == rebuilds_before
+                 .req({"cpu": "100m"}).obj()])
+            # refused the incremental path: rebuilt over the new node set
+            assert session.incremental_hits == hits_before
+            assert session.rebuilds == rebuilds_before + 1
+            assert "n3" not in cluster.node_names
+            assert cluster.node_names[int(assignments[0])] != "n3"
         finally:
             sched.stop()
 

@@ -1,8 +1,8 @@
 """The scheduling cycle's stages as tracer spans, on the profiler's clock.
 
 A 48-node cluster with zone and hostname spread pods, driven one
-``run_batch`` pass at a time: the span tree of a pass, the discard
-counter and the reseed reasons a churn delete leaves, the disabled
+``run_batch`` pass at a time: the span tree of a pass, the rebuild
+and reseed reason a churn delete between passes leaves, the disabled
 tracer's shared no-op, and the spans' ``TraceAnnotation`` twins in a
 CPU profiler trace, read back by the benchmark's own trace reduction.
 """
@@ -94,7 +94,6 @@ def test_pass_span_tree(cluster, tracer):
     by_id = {r[5]: r for r in recs}
     [cycle] = _records(tracer, "queue.cycle")
     assert cycle[8]["pods"] == 8 and cycle[8]["committed"] == 8
-    assert cycle[8]["discarded"] == 0
     # the warm-up left the session invalidated: a full rebuild
     assert cycle[8]["rebuild"] in ("full", "state_only")
     names = {r[0] for r in recs}
@@ -131,7 +130,6 @@ def test_encode_span_counts_signatures(cluster, tracer):
     assert bs.run_batch(pop_timeout=0.0) == 8
     store.create_pods([_pod(f"q-{k}") for k in range(8)])
     assert bs.run_batch(pop_timeout=0.0) == 8
-    bs.flush()
     by_id = {r[5]: r for r in _records(tracer)}
     encodes = _records(tracer, "solve.encode")
     rebuilt = [r for r in encodes
@@ -153,32 +151,26 @@ def test_idle_pass_leaves_no_record(cluster, tracer):
 
 
 def test_discard_after_churn_delete(cluster, tracer):
-    """A spread pod deleted while a solved batch waits for its commit:
-    the batch is discarded, its pods solved again after a full rebuild
-    whose reason is the deleted pod's spread terms."""
+    """A spread pod deleted between two passes, with a backlog queued:
+    nothing is discarded, and the next pass solves its own drain after
+    a rebuild whose reason is the deleted pod's spread terms, then
+    commits all of it."""
     store, bs = cluster
     mirror = bs.session._mirror
     assert mirror is not None
     store.create_pods([_pod(f"p-{k}") for k in range(24)])
-    # 16 pods solved and held (8 more are queued), nothing committed
+    # 16 pods solved and committed, 8 more queued
     assert bs.run_batch(pop_timeout=0.0) == 16
-    assert bs._pending is not None
-    held = len(bs._pending["batchable"])
-    assert held == 16
     before = dict(mirror.info()["reseed_reasons"])
     store.delete_pod("default", "run-0")
-    assert bs.run_batch(pop_timeout=0.0) == held
-    assert bs.discarded_pods == held
+    assert bs.run_batch(pop_timeout=0.0) == 8
     cycles = _records(tracer, "queue.cycle")
-    assert [c[8]["discarded"] for c in cycles] == [0, held]
-    # the pods thrown away are the pods solved again, all of them
-    assert cycles[-1][8]["pods"] == held
-    [discard] = _records(tracer, "sidecar.discard")
-    assert discard[8]["pods"] == held
-    # the discarded solve's carry is put back before the re-solve
-    assert discard[8]["carry"] == "restored"
-    assert discard[8]["restored"] == held
-    assert mirror.info()["carry_restores"] == 1
+    assert [(c[8]["pods"], c[8]["committed"]) for c in cycles] == \
+        [(16, 16), (8, 8)]
+    for c in cycles:
+        assert "discarded" not in c[8]
+    assert _records(tracer, "sidecar.discard") == []
+    assert cycles[-1][8]["rebuild"] in ("full", "state_only")
     rebuilds = _records(tracer, "solve.rebuild")
     assert rebuilds[-1][8]["reason"] == "pod_spread_terms"
     after = mirror.info()["reseed_reasons"]
@@ -188,10 +180,7 @@ def test_discard_after_churn_delete(cluster, tracer):
     # the failed catch-up is on the tracer too
     scatter = _records(tracer, "solve.scatter")[-1]
     assert scatter[8]["events"] == 0 and scatter[8]["bytes"] == 0
-    # everything still binds
-    while bs.run_batch(pop_timeout=0.0):
-        pass
-    bs.flush()
+    assert bs.run_batch(pop_timeout=0.0) == 0
     assert all(p.spec.node_name for p in store.list_pods())
 
 

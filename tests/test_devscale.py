@@ -165,8 +165,6 @@ class TestShardedSessionAccounting:
                 sched.queue.flush_backoff_completed()
                 if bs.run_batch(pop_timeout=0.0):
                     continue
-                if bs.flush():
-                    continue
                 if sched.queue.num_active() == 0 \
                         and sched.queue.num_backoff() == 0:
                     break

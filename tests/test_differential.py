@@ -265,8 +265,6 @@ def _pump(sched, bs, deadline_s=120.0):
         )
         if progressed:
             continue
-        if bs is not None and bs.flush():
-            continue
         if sched.queue.num_active() == 0 and sched.queue.num_backoff() == 0:
             break
         time.sleep(0.01)
@@ -670,7 +668,7 @@ class TestPreemptionPlannerEquivalence:
                 while time.monotonic() < deadline:
                     sched.queue.flush_backoff_completed()
                     if bs is not None:
-                        if bs.run_batch(pop_timeout=0.0) or bs.flush():
+                        if bs.run_batch(pop_timeout=0.0):
                             continue
                     else:
                         sched.schedule_one(pop_timeout=0.0)

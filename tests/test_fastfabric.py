@@ -593,7 +593,6 @@ class TestGangSessionStability:
                 bound = sum(1 for p in store.list_pods()
                             if p.spec.node_name)
             assert bound == 20
-            bs.flush()
             sched.wait_for_inflight_bindings(timeout=10.0)
             # the solve that placed the gangs must not have poisoned
             # the session: WAIT-parked assumes are sanctioned mutations

@@ -143,7 +143,6 @@ class TestCriticalPath:
             assert phase_of(name) == "encode", name
         assert phase_of("solve.block") == "solve"
         assert phase_of("sidecar.drain") == "queue"
-        assert phase_of("sidecar.discard") == "queue"
         assert phase_of("sidecar.serial") == "solve"
         assert phase_of("bind.bulk") == "bind"
 

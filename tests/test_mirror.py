@@ -50,11 +50,9 @@ def _pump(sched, bs, timeout=60.0):
         sched.queue.flush_backoff_completed()
         if bs.run_batch(pop_timeout=0.0):
             continue
-        if sched.queue.pending_active_count() == 0 and \
-                bs._pending is None:
+        if sched.queue.pending_active_count() == 0:
             break
         time.sleep(0.01)
-    bs.flush()
     assert sched.wait_for_inflight_bindings()
 
 
@@ -88,14 +86,11 @@ def _gang(w, gangs=2, size=4, cpu="2"):
 
 
 def _run_scenario(scenario: str, seed: int, mirror_on: bool,
-                  monkeypatch, *, devices=None, max_batch=32,
-                  pipeline=True):
+                  monkeypatch, *, devices=None, max_batch=32):
     """One arm of the differential: drive a seeded event sequence and
     return (bound set, mirror info). ``devices`` selects the sharded
-    tier at that mesh width; None = the process default backend.
-    ``pipeline`` False runs the serialized loop (``KTPU_PIPELINE=off``)."""
+    tier at that mesh width; None = the process default backend."""
     monkeypatch.setenv("KTPU_MIRROR", "on" if mirror_on else "off")
-    monkeypatch.setenv("KTPU_PIPELINE", "on" if pipeline else "off")
     rng = np.random.default_rng(seed)
     store = ClusterStore()
     n_nodes = 10
@@ -136,9 +131,9 @@ def _run_scenario(scenario: str, seed: int, mirror_on: bool,
             wave(2, 16)
         elif scenario == "node_death":
             wave(0, 24)
-            # one cycle dispatches a solve that is still in flight when
-            # the node dies — the suspect-batch discard plus the
-            # node-SET epoch bump both fire mid-sequence
+            # one cycle commits a batch with the rest of the wave still
+            # queued when the node dies — the node-SET epoch bump fires
+            # mid-sequence
             store.create_pods([
                 MakePod().name(f"w1-p{i}").uid(f"u1-{i}")
                 .req({"cpu": "300m"}).obj()
@@ -166,13 +161,9 @@ def _run_scenario(scenario: str, seed: int, mirror_on: bool,
                 .req({"cpu": f"{int(rng.integers(1, 6)) * 100}m"}).obj()
                 for i in range(max_batch + max_batch // 2)
             ])
-            if bs.pipeline_enabled:
-                # one batch solved and held (half a batch more queued)
-                # when the deletes land behind it: the pass after them
-                # discards it and solves its pods again. The serialized
-                # loop holds nothing, so there the deletes land first.
-                bs.run_batch(pop_timeout=0.1)
-                assert bs._pending is not None
+            # one batch committed (half a batch more queued) when the
+            # deletes land: the next pass catches them up first
+            assert bs.run_batch(pop_timeout=0.1) == max_batch
             for p in doomed:
                 store.delete_pod(p.metadata.namespace, p.metadata.name)
             _pump(sched, bs)
@@ -303,10 +294,9 @@ class TestMirrorDifferential:
 
 
 class TestDiscardUnderChurn:
-    """A held batch discarded for deletes behind it is solved again as
-    if it had seen them first: mirror on (carry restored, deletes
-    scattered), mirror off (rebuild) and the serialized loop bind
-    alike."""
+    """Deletes that land between two calls, with a backlog queued, are
+    seen by the next solve: mirror on (deletes scattered) and mirror
+    off (rebuild) bind alike."""
 
     @pytest.mark.parametrize("seed", [3, 14, 77])
     def test_bound_sets_match(self, seed, monkeypatch):
@@ -314,11 +304,9 @@ class TestDiscardUnderChurn:
                                 monkeypatch)
         off, _ = _run_scenario("discard_under_churn", seed, False,
                                monkeypatch)
-        serial, _ = _run_scenario("discard_under_churn", seed, True,
-                                  monkeypatch, pipeline=False)
-        assert on == off == serial
-        assert ion["carry_restores"] >= 1
+        assert on == off
         assert ion["catch_ups"] > 0
+        assert ion["reseeds"] == 0
 
 
 def _state_rows(state) -> np.ndarray:
@@ -328,10 +316,10 @@ def _state_rows(state) -> np.ndarray:
 
 
 class TestCarryRestore:
-    """Hold a solved batch, delete bound pods behind it, discard the
-    batch: with its carry put back and the deletes scattered, the
-    resident state equals a fresh state-only rebuild on the same cache,
-    bit for bit, on every backend the mirror serves."""
+    """Commit a batch, delete bound pods behind it, catch up: with the
+    deletes scattered, the resident state equals a fresh state-only
+    rebuild on the same cache, bit for bit, on every backend the
+    mirror serves."""
 
     def _check(self, backend=None):
         from kubernetes_tpu.ops.encode import BatchEncoder
@@ -354,14 +342,9 @@ class TestCarryRestore:
             _pump(sched, bs)
             store.create_pods(pods(1, 24))
             assert bs.run_batch(pop_timeout=0.1) == 16
-            held = bs._pending
-            assert held is not None
-            bound = [p for p in store.list_pods()
-                     if p.spec.node_name and p.metadata.name.startswith("w0")]
-            for p in bound[:5]:
-                store.delete_pod(p.metadata.namespace, p.metadata.name)
+            assert sched.wait_for_inflight_bindings()
             sess = bs.session
-            assert not sess.mirror_current()
+            assert sess.mirror_current()
             static = sess._static
             do, _ = _state_planes(static.r, static.sc, static.t, static.sv)
             n = sess._cluster.allocatable.shape[0]
@@ -370,30 +353,33 @@ class TestCarryRestore:
                 planes = np.asarray(state.planes)
                 return planes.reshape(planes.shape[0], n)[do["pod_count"]]
 
-            phantom = pod_count(sess._state).sum()
-            assert sess.restore_carry(held["carry"])
-            assert sess.carry_restores == 1
+            # the carry holds every committed pod, and nothing else
+            before = pod_count(sess._state).sum()
+            assert before == 24 + 16
+            bound = [p for p in store.list_pods()
+                     if p.spec.node_name and p.metadata.name.startswith("w0")]
+            for p in bound[:5]:
+                store.delete_pod(p.metadata.namespace, p.metadata.name)
+            assert not sess.mirror_current()
             applied = sess._mirror.catch_up(sess._last_seq,
                                             sched.cache.mutation_seq)
             assert applied is not None
             got = _state_rows(sess._state)
-            restored = pod_count(sess._state).sum()
+            after = pod_count(sess._state).sum()
 
             sched.algorithm.update_snapshot()
             cluster, batch = BatchEncoder(
                 sched.algorithm.snapshot, pad_nodes=sess.pad_nodes,
-            ).encode([q.pod for q, _ in held["batchable"]], pad_pods=16)
+            ).encode(sched.queue.pending_pods()[:16], pad_pods=16)
             be = sess._active
             if hasattr(be, "prepare_state_only"):
                 fresh = be.prepare_state_only(cluster, batch)
             else:
                 _static, fresh = be.prepare(cluster, batch)
             assert np.array_equal(got, _state_rows(fresh))
-            # the carry left in place held the discarded batch and none
-            # of the deletes
-            assert phantom == restored + 16 + 5
-            assert restored == len([p for p in store.list_pods()
-                                    if p.spec.node_name])
+            assert after == before - 5
+            assert after == len([p for p in store.list_pods()
+                                 if p.spec.node_name])
         finally:
             sched.stop()
 

@@ -165,12 +165,6 @@ class TestDiagFmtRoundTrip:
                                      "reseeds": 0})
         parsed = diagfmt.parse_diag(diagfmt.format_diag([seg]))
         assert "encode_share" not in parsed["mirror"]
-        assert "carry_restores" not in parsed["mirror"]
-        # discarded batches whose carry was put back
-        seg = diagfmt.format_mirror({"events": 1, "scatter_mb": 0.0,
-                                     "reseeds": 0, "carry_restores": 7})
-        parsed = diagfmt.parse_diag(diagfmt.format_diag([seg]))
-        assert parsed["mirror"]["carry_restores"] == 7
 
 
 # ---------------------------------------------------------------------------

@@ -1,36 +1,37 @@
-"""Streaming-scheduler pipeline tests (ISSUE 14): the differential
-guard between the double-buffered loop and the ``KTPU_PIPELINE=off``
-barrier arm, the stage-handoff contract, the overlap telemetry, and
-the tier-1 sustained-arrival mini-cell.
+"""The batch scheduler's one loop: each ``run_batch`` call drains,
+solves and commits its batch before it returns.
 
-The differential guard is the PR's hardest promise: over identical
-seeded event sequences — including a gang workload and a mid-run
-node-death drift — the pipelined loop and the serialized arm must
-produce a BIT-IDENTICAL bound set (same pods → same nodes). Both arms
-run with ``adaptive_chunk=False`` and the same ``max_batch`` so the
-drains partition identically; everything else (incremental mirror,
-state carry, tie-breaks) must line up by construction.
+The differential guard drives identical seeded event sequences —
+including a gang workload and a mid-run node-death drift — twice
+through the loop and requires a BIT-IDENTICAL bound set (same pods →
+same nodes) with the sequence's invariants. Both runs use
+``adaptive_chunk=False`` and the same ``max_batch`` so the drains
+partition identically; everything else (incremental mirror, state
+carry, tie-breaks) must line up by construction. The backlog tests
+churn bound pods between calls, as the benchmark's client does, and
+require every solved pod to be committed in the call that solved it.
 """
 
 from __future__ import annotations
 
 import time
 
+import numpy as np
 import pytest
 
 from kubernetes_tpu.apiserver.store import ClusterStore
 from kubernetes_tpu.config.feature_gates import FeatureGates
 from kubernetes_tpu.scheduler.scheduler import Scheduler
-from kubernetes_tpu.sidecar import TPUBatchScheduler, attach_batch_scheduler
+from kubernetes_tpu.sidecar import attach_batch_scheduler
 from kubernetes_tpu.testing import MakeNode, MakePod
 
 
-def _make_sched(store, pipeline, max_batch=32):
+def _make_sched(store, max_batch=32, backend=None):
     sched = Scheduler.create(
         store, feature_gates=FeatureGates({"TPUBatchScheduler": True}),
         provider="GangSchedulingProvider")
     bs = attach_batch_scheduler(sched, max_batch=max_batch,
-                                adaptive_chunk=False, pipeline=pipeline)
+                                adaptive_chunk=False, backend=backend)
     sched.start()
     return sched, bs
 
@@ -41,11 +42,9 @@ def _pump(sched, bs, timeout=30.0):
         sched.queue.flush_backoff_completed()
         if bs.run_batch(pop_timeout=0.0):
             continue
-        if sched.queue.pending_active_count() == 0 and \
-                bs._pending is None:
+        if sched.queue.pending_active_count() == 0:
             break
         time.sleep(0.01)
-    bs.flush()
     assert sched.wait_for_inflight_bindings()
 
 
@@ -54,19 +53,18 @@ def _bound_set(store):
                   for p in store.list_pods())
 
 
-def _run_event_sequence(pipeline: bool, waves, nodes=12, node_cpu="8",
+def _run_event_sequence(waves, nodes=12, node_cpu="8",
                         kill_node_after=None, max_batch=32):
-    """Drive one arm through a seeded event sequence: each wave is a
+    """Drive the loop through a seeded event sequence: each wave is a
     list of pod builders, pumped to quiescence before the next;
     ``kill_node_after`` deletes that node name after the given wave
-    index (the mid-run drift — both arms see it at the same quiesce
-    point)."""
+    index (the mid-run drift, at a quiesce point)."""
     store = ClusterStore()
     for i in range(nodes):
         store.add_node(MakeNode().name(f"n{i}")
                        .capacity({"cpu": node_cpu, "memory": "16Gi"})
                        .obj())
-    sched, bs = _make_sched(store, pipeline, max_batch=max_batch)
+    sched, bs = _make_sched(store, max_batch=max_batch)
     try:
         for wi, wave in enumerate(waves):
             store.create_pods([mk() for mk in wave])
@@ -80,7 +78,6 @@ def _run_event_sequence(pipeline: bool, waves, nodes=12, node_cpu="8",
         import gc
 
         gc.collect()   # don't leave a deferred-GC pause for later tests
-
 
 def _plain_waves(n_waves=3, per_wave=40, cpu="1", offset=0):
     return [
@@ -110,27 +107,27 @@ def _gang_wave(w, gangs=3, size=4, cpu="2"):
 
 class TestDifferentialGuard:
     def test_contended_waves_bit_identical(self):
-        """Capacity-contended waves (more pods than fit): the two arms
-        must agree on exactly WHICH pods bound and WHERE."""
+        """Capacity-contended waves (more pods than fit): two runs must
+        agree on exactly WHICH pods bound and WHERE."""
         waves = _plain_waves(3, 40)   # 120 x 1cpu vs 96 cores
-        a = _run_event_sequence(True, waves)
-        b = _run_event_sequence(False, waves)
+        a = _run_event_sequence(waves)
+        b = _run_event_sequence(waves)
         assert a == b
         assert sum(1 for _, n in a if n) == 96   # capacity exactly
 
     def test_gang_workload_bit_identical(self):
         """Gangs (Permit-parked, async binding cycles) interleaved
-        with plain pods — the arms must still agree pod-for-pod."""
+        with plain pods — two runs must still agree pod-for-pod."""
         waves = [
             _plain_waves(1, 20)[0],
             _gang_wave(1, gangs=3, size=4),
             _plain_waves(1, 10, offset=2)[0]
             + _gang_wave(2, gangs=2, size=4),
         ]
-        a = _run_event_sequence(True, waves)
-        b = _run_event_sequence(False, waves)
+        a = _run_event_sequence(waves)
+        b = _run_event_sequence(waves)
         assert a == b
-        # the gangs actually landed (atomically) in both arms
+        # the gangs landed atomically
         for w, g, size in ((1, 0, 4), (1, 1, 4), (1, 2, 4),
                            (2, 0, 4), (2, 1, 4)):
             members = [n for (name, n) in a
@@ -139,12 +136,12 @@ class TestDifferentialGuard:
 
     def test_mid_run_node_death_bit_identical(self):
         """A node deleted mid-sequence (after wave 0's quiesce): the
-        node-SET epoch bump forces both arms through the drift
-        re-encode, and the remaining waves must still land
-        identically — with nothing placed on the dead node."""
+        node-SET epoch bump forces the drift re-encode, and the
+        remaining waves must land identically in both runs — with
+        nothing placed on the dead node."""
         waves = _plain_waves(3, 30)
-        a = _run_event_sequence(True, waves, kill_node_after=(0, "n3"))
-        b = _run_event_sequence(False, waves, kill_node_after=(0, "n3"))
+        a = _run_event_sequence(waves, kill_node_after=(0, "n3"))
+        b = _run_event_sequence(waves, kill_node_after=(0, "n3"))
         assert a == b
         # post-death waves never bound onto the deleted node
         for name, node in a:
@@ -153,149 +150,213 @@ class TestDifferentialGuard:
                     f"{name} bound to the dead node after its deletion"
 
     def test_mid_flight_node_death_loses_nothing(self):
-        """Drift WHILE a batch is in flight (pipelined arm only — the
-        barrier arm has no in-flight window): dispatch a solve, kill a
-        node before its commit cycle, keep pumping. The mirror guard
-        must discard the suspect batch and re-solve; every pod still
-        binds, none onto the dead node."""
+        """A node dies between two ``run_batch`` calls under a backlog:
+        one call commits a batch, the node is deleted with 32 pods
+        still queued, and pumping on binds every pod, none of the
+        later ones onto the dead node."""
         store = ClusterStore()
         for i in range(8):
             store.add_node(MakeNode().name(f"n{i}")
                            .capacity({"cpu": "8", "memory": "16Gi"})
                            .obj())
-        sched, bs = _make_sched(store, pipeline=True, max_batch=16)
+        sched, bs = _make_sched(store, max_batch=16)
         try:
             store.create_pods([
                 MakePod().name(f"p{i}").uid(f"u{i}")
                 .req({"cpu": "1"}).obj()
                 for i in range(48)
             ])
-            # one cycle: dispatches a solve and (first call) holds it
-            bs.run_batch(pop_timeout=0.1)
-            store.delete_node("n2")   # drift while in flight
+            assert bs.run_batch(pop_timeout=0.1) == 16
+            assert sched.wait_for_inflight_bindings()
+            first = {p.metadata.name for p in store.list_pods()
+                     if p.spec.node_name}
+            assert len(first) == 16
+            store.delete_node("n2")   # drift with a backlog queued
             _pump(sched, bs)
             pods = store.list_pods()
             assert all(p.spec.node_name for p in pods)
             assert len(pods) == 48
-            assert not any(p.spec.node_name == "n2" for p in pods)
+            assert not any(p.spec.node_name == "n2" for p in pods
+                           if p.metadata.name not in first)
         finally:
             sched.stop()
 
 
 class TestKillSwitch:
-    def test_env_kill_switch(self, monkeypatch):
-        monkeypatch.setenv("KTPU_PIPELINE", "off")
-        store = ClusterStore()
-        sched = Scheduler.create(
-            store,
-            feature_gates=FeatureGates({"TPUBatchScheduler": True}))
-        bs = attach_batch_scheduler(sched)
-        assert bs.pipeline_enabled is False
-        assert bs.pipeline_info() is None
-        monkeypatch.setenv("KTPU_PIPELINE", "on")
-        assert TPUBatchScheduler(sched).pipeline_enabled is True
-        monkeypatch.delenv("KTPU_PIPELINE")
-        assert TPUBatchScheduler(sched).pipeline_enabled is True
-
     def test_serialized_arm_never_holds_a_batch(self):
-        """The barrier arm commits every solve in the same call:
-        ``_pending`` must never survive a ``run_batch`` return, and
-        ``flush`` is a no-op."""
+        """``run_batch`` never holds a batch: every pod a call drains
+        is bound when the call returns (its binds waited out)."""
         store = ClusterStore()
         for i in range(4):
             store.add_node(MakeNode().name(f"n{i}")
                            .capacity({"cpu": "8", "memory": "16Gi"})
                            .obj())
-        sched, bs = _make_sched(store, pipeline=False, max_batch=8)
+        sched, bs = _make_sched(store, max_batch=8)
         try:
             store.create_pods([
                 MakePod().name(f"p{i}").uid(f"u{i}")
                 .req({"cpu": "500m"}).obj()
                 for i in range(30)
             ])
-            while bs.run_batch(pop_timeout=0.0):
-                assert bs._pending is None
-            assert bs.flush() == 0
-            sched.wait_for_inflight_bindings()
+            drained = 0
+            while True:
+                n = bs.run_batch(pop_timeout=0.0)
+                if not n:
+                    break
+                drained += n
+                assert sched.wait_for_inflight_bindings()
+                bound = sum(1 for p in store.list_pods()
+                            if p.spec.node_name)
+                assert bound == drained
+            assert drained == 30
+        finally:
+            sched.stop()
+
+
+def _spread_pod(name, node=""):
+    w = (MakePod().name(name).uid(f"uid-{name}").label("foo", "bar")
+         .req({"cpu": "100m", "memory": "500Mi"})
+         .spread_constraint(5, "kubernetes.io/hostname", "DoNotSchedule",
+                            {"foo": "bar"})
+         .spread_constraint(5, "topology.kubernetes.io/zone",
+                            "DoNotSchedule", {"foo": "bar"}))
+    if node:
+        w = w.node(node)
+    return w.obj()
+
+
+def _plain_pod(name, node=""):
+    w = MakePod().name(name).uid(f"uid-{name}").req(
+        {"cpu": "100m", "memory": "500Mi"})
+    if node:
+        w = w.node(node)
+    return w.obj()
+
+
+class TestOneLoop:
+    """The benchmark's backlog shape at a test's size: a standing
+    backlog topped up before every call, and for each pod bound the
+    oldest running pod deleted between calls."""
+
+    @pytest.mark.parametrize("make", [_spread_pod, _plain_pod],
+                             ids=["spread", "plain"])
+    def test_backlog_commits_every_solve_in_its_call(self, make):
+        store = ClusterStore()
+        nodes = 12
+        for i in range(nodes):
+            store.add_node(MakeNode().name(f"n{i}")
+                           .label("topology.kubernetes.io/zone",
+                                  f"z{i % 3}")
+                           .capacity({"cpu": "4", "memory": "32Gi"})
+                           .obj())
+        running = [f"run-{j}" for j in range(2 * nodes)]
+        store.create_pods([make(nm, f"n{j % nodes}")
+                           for j, nm in enumerate(running)])
+        sched, bs = _make_sched(store, max_batch=16)
+        try:
+            created = 0
+
+            def top_up(want):
+                nonlocal created
+                store.create_pods([make(f"p-{created + k}")
+                                   for k in range(want)])
+                created += want
+
+            top_up(40)
+            bound_before = 0
+            for _ in range(6):
+                committed0 = bs.batch_committed_pods
+                n = bs.run_batch(pop_timeout=0.1)
+                assert n == 16
+                # every pod drained this call was solved and committed
+                # in it: nothing waits for the next call
+                assert bs.batch_committed_pods - committed0 == n
+                assert sched.wait_for_inflight_bindings()
+                bound = sum(1 for p in store.list_pods()
+                            if p.spec.node_name
+                            and p.metadata.name.startswith("p-"))
+                assert bound == bound_before + n
+                bound_before = bound
+                # churn: one running pod out per pod bound, the
+                # backlog back up
+                for nm in running[:n]:
+                    store.delete_pod("default", nm)
+                del running[:n]
+                top_up(n)
+        finally:
+            sched.stop()
+
+    def test_run_batch_leaves_no_carry_clone(self):
+        """A solve takes the resident state as its input and keeps only
+        the state it returns: the native backend, which updates its
+        state in place, is never asked for a clone of it."""
+        from kubernetes_tpu.ops import native_backend
+
+        backend = native_backend.CppBackend()
+        clones = []
+        orig = backend.warm_state
+
+        def counting(state):
+            clones.append(1)
+            return orig(state)
+
+        backend.warm_state = counting
+        store = ClusterStore()
+        for i in range(6):
+            store.add_node(MakeNode().name(f"n{i}")
+                           .capacity({"cpu": "16", "memory": "32Gi"})
+                           .obj())
+        sched, bs = _make_sched(store, max_batch=8, backend=backend)
+        try:
+            store.create_pods([MakePod().name(f"p{i}").uid(f"u{i}")
+                               .req({"cpu": "100m"}).obj()
+                               for i in range(40)])
+            _pump(sched, bs)
             assert all(p.spec.node_name for p in store.list_pods())
+            assert bs.session.incremental_hits >= 3
+            assert clones == []
         finally:
             sched.stop()
 
-
-class TestStageHandoff:
-    def test_carry_never_reencoded_between_chained_solves(self):
-        """The donated-carry contract: once the session has rebuilt,
-        back-to-back pipelined solves chain on the device-resident
-        state carry — ``prepare``/``prepare_state_only`` must NOT run
-        again (re-encoding a carry a donating backend already consumed
-        would corrupt the mirror)."""
+    def test_solve_result_is_always_materialized(self, monkeypatch):
+        """Every solve ``run_batch`` makes hands back host assignments,
+        incremental and rebuild solves alike: there is no device handle
+        to materialize later."""
+        monkeypatch.setenv("KTPU_SOLVER", "xla")
         store = ClusterStore()
-        for i in range(8):
+        for i in range(6):
             store.add_node(MakeNode().name(f"n{i}")
                            .capacity({"cpu": "16", "memory": "32Gi"})
                            .obj())
-        sched, bs = _make_sched(store, pipeline=True, max_batch=16)
+        sched, bs = _make_sched(store, max_batch=8)
+        results = []
+        orig = bs.session.solve
+
+        def recording(*a, **kw):
+            out = orig(*a, **kw)
+            results.append(out[0])
+            return out
+
+        bs.session.solve = recording
         try:
-            # settle the first rebuild
-            store.create_pods([MakePod().name("seed").uid("useed")
-                               .req({"cpu": "100m"}).obj()])
+            store.create_pods([MakePod().name(f"p{i}").uid(f"u{i}")
+                               .req({"cpu": "100m"}).obj()
+                               for i in range(24)])
             _pump(sched, bs)
-            active = bs.session._active
-            calls = []
-            orig_prepare = active.prepare
-
-            def counting_prepare(cluster, batch):
-                calls.append("prepare")
-                return orig_prepare(cluster, batch)
-
-            active.prepare = counting_prepare
-            if hasattr(active, "prepare_state_only"):
-                orig_so = active.prepare_state_only
-
-                def counting_so(cluster, batch):
-                    calls.append("state_only")
-                    return orig_so(cluster, batch)
-
-                active.prepare_state_only = counting_so
-            store.create_pods([
-                MakePod().name(f"p{i}").uid(f"u{i}")
-                .req({"cpu": "200m"}).obj()
-                for i in range(64)
-            ])
-            hits_before = bs.session.incremental_hits
-            _pump(sched, bs)
-            assert bs.session.incremental_hits > hits_before
-            assert calls == [], \
-                f"pipelined solves re-encoded the carry: {calls}"
-        finally:
-            sched.stop()
-
-    def test_depth_tracked_under_backlog(self):
-        store = ClusterStore()
-        for i in range(8):
-            store.add_node(MakeNode().name(f"n{i}")
-                           .capacity({"cpu": "16", "memory": "32Gi"})
-                           .obj())
-        sched, bs = _make_sched(store, pipeline=True, max_batch=16)
-        try:
-            store.create_pods([
-                MakePod().name(f"p{i}").uid(f"u{i}")
-                .req({"cpu": "100m"}).obj()
-                for i in range(64)
-            ])
-            _pump(sched, bs)
-            # 64 pods through a 16-pad loop: at least solve N + commit
-            # N-1 were in flight together at some point
-            assert bs.pipeline_depth_max >= 2
+            assert len(results) >= 3
+            assert bs.session.rebuilds >= 1
+            assert bs.session.incremental_hits >= 1
+            for out in results:
+                assert isinstance(out, np.ndarray), type(out)
         finally:
             sched.stop()
 
 
 class TestSustainedMiniCell:
-    """Satellite 6: the tier-1 sustained-arrival cell — open-loop
-    arrivals through the replay engine at compressed scale, asserting
-    the pipeline genuinely overlaps and the staleness SLO stays green,
-    inside the fast-suite time budget."""
+    """The tier-1 sustained-arrival cell — open-loop arrivals through
+    the replay engine at compressed scale, asserting nothing is lost
+    and the staleness SLO stays green, inside the fast-suite time
+    budget."""
 
     def test_overlap_occurs_and_staleness_green(self):
         from kubernetes_tpu.harness.sustained import run_sustained_cell
@@ -304,32 +365,10 @@ class TestSustainedMiniCell:
                                   wait_timeout=90.0)
         assert cell["lost"] == 0
         assert cell["ever_bound"] == cell["injected"] == 400
-        # the pipeline actually overlapped host work with in-flight
-        # device time — the tentpole's measurable claim
-        assert cell["overlapped_cycles"] > 0
-        assert cell["overlap_share"] > 0.0
-        # depth ≥ 2 under a guaranteed backlog is pinned by
-        # TestStageHandoff; open-loop trickle timing only guarantees
-        # the pipeline was on
-        assert cell["pipeline"]["depth"] >= 1
-        # the deeper in-flight window never let the solve run stale:
-        # PR 8's staleness SLO verdict holds under open-loop arrivals
+        # the solve never ran against a stale mirror: the snapshot
+        # staleness SLO verdict holds under open-loop arrivals
         assert cell["staleness_verdict"] in (None, "ok")
         assert cell["p99_arrival_to_bind_ms"] < 2000
-
-    def test_barrier_arm_reports_no_overlap(self):
-        """The same cell with KTPU_PIPELINE=off: eager solves open no
-        in-flight window, so overlap telemetry must read zero — the
-        A/B that proves overlap_share measures the pipeline and not an
-        artifact."""
-        from kubernetes_tpu.harness.sustained import run_sustained_cell
-
-        cell = run_sustained_cell(pods=200, qps=400.0, max_batch=64,
-                                  pipeline=False, wait_timeout=90.0)
-        assert cell["lost"] == 0
-        assert cell["overlapped_cycles"] == 0
-        assert cell["overlap_share"] == 0.0
-        assert cell["pipeline"] is None
 
 
 class TestOverlapTelemetry:

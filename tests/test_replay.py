@@ -280,7 +280,6 @@ def _pump_store_replay(store, trace, time_scale, *, timeout=120.0,
             elif now - quiet > 1.0:
                 break
             time.sleep(0.005)
-        bs.flush()
         sched.wait_for_inflight_bindings(timeout=10.0)
         return eng.finish()
     finally:
@@ -334,7 +333,6 @@ class TestOpenLoopDifferential:
                     elapsed = wait_all_bound(store_b, names, 0.01)
                     if elapsed is not None:
                         break
-            bs.flush()
             sched.wait_for_inflight_bindings(timeout=10.0)
         finally:
             sched.stop()

@@ -200,9 +200,10 @@ class TestIncrementalSession:
 
 class TestPipelinedBatches:
     def test_stale_pending_is_resolved_not_serialized(self):
-        """A held batch whose mirror diverges (external node add between
-        its solve and commit) must be re-solved against a fresh snapshot
-        and still bind everything correctly."""
+        """Drift between two calls (an external node add after a batch
+        committed, with a backlog queued): the next solve rebuilds from
+        a fresh snapshot and everything still binds correctly on the
+        batch path."""
         store = ClusterStore()
         for i in range(4):
             store.add_node(
@@ -212,16 +213,20 @@ class TestPipelinedBatches:
         sched, bs = make_batch_scheduler(store, max_batch=8)
         for i in range(24):
             store.create_pod(MakePod().name(f"p{i}").req({"cpu": "1"}).obj())
-        # first cycle: solves 8, holds them pending (queue still has 16)
-        bs.run_batch(pop_timeout=0.1)
-        assert bs._pending is not None
-        # external mutation while the batch is in flight
+        # first cycle: solves and commits 8 (queue still has 16)
+        assert bs.run_batch(pop_timeout=0.1) == 8
+        assert bs.batch_committed_pods == 8
+        rebuilds = bs.session.rebuilds
+        # external mutation between the calls
         store.add_node(
             MakeNode().name("late").capacity({"cpu": "8", "memory": "16Gi"}).obj()
         )
         drain(sched, bs)
+        assert bs.session.rebuilds > rebuilds
         bound = [p for p in store.list_pods() if p.spec.node_name]
         assert len(bound) == 24
+        assert bs.batch_committed_pods == 24
+        assert bs.serial_fallback_pods == 0
         per_node = {}
         for p in bound:
             per_node[p.spec.node_name] = per_node.get(p.spec.node_name, 0) + 1
@@ -241,10 +246,10 @@ class TestPipelinedBatches:
                 .capacity({"cpu": "4", "memory": "8Gi"}).obj()
             )
         sched, bs = make_batch_scheduler(store, max_batch=8)
-        # wave 1: plain pods (fills the pipeline)
+        # wave 1: plain pods (a backlog of two batches)
         for i in range(16):
             store.create_pod(MakePod().name(f"a{i}").req({"cpu": "1"}).obj())
-        bs.run_batch(pop_timeout=0.1)    # solve 8, hold pending
+        bs.run_batch(pop_timeout=0.1)    # solve and commit 8
         # wave 2: spread-constrained pods -> new tracked constraint ->
         # encode space mismatch -> rebuild path
         for i in range(12):
@@ -278,9 +283,9 @@ class TestPipelinedBatches:
 
 class TestCarriedBatchRepartition:
     def test_pod_deleted_in_flight_is_dropped_on_carry(self):
-        """ADVICE r1: a pod deleted while its solved batch was in flight
-        must be dropped when the discarded batch's pods are carried over,
-        not re-committed from a stale QueuedPodInfo."""
+        """A queued pod deleted between two calls, together with drift
+        that forces a rebuild, is never drained or bound from a stale
+        QueuedPodInfo."""
         store = ClusterStore()
         for i in range(4):
             store.add_node(
@@ -291,12 +296,11 @@ class TestCarriedBatchRepartition:
         for i in range(16):
             store.create_pod(MakePod().name(f"p{i}").uid(f"u{i}")
                              .req({"cpu": "1"}).obj())
-        bs.run_batch(pop_timeout=0.1)      # solve 8, hold pending
-        assert bs._pending is not None
-        victim = bs._pending["batchable"][0][0].pod
+        assert bs.run_batch(pop_timeout=0.1) == 8   # solve, commit 8
+        victim = next(p for p in store.list_pods() if not p.spec.node_name)
         store.delete_pod(victim.namespace, victim.name)
-        # external cache mutation -> mirror diverges -> batch discarded,
-        # pods carried over through the fresh partition
+        # external cache mutation -> mirror diverges -> the next solve
+        # rebuilds over the remaining queue
         store.add_node(
             MakeNode().name("late").capacity({"cpu": "8", "memory": "16Gi"}).obj()
         )

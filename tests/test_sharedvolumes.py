@@ -71,7 +71,6 @@ def _run_batch(store, pods, max_batch=64, timeout=120.0):
                       for c in p.status.conditions)
                for p in store.list_pods()):
             break
-    bs.flush()
     sched.wait_for_inflight_bindings()
     placements = {p.metadata.name: p.spec.node_name
                   for p in store.list_pods() if p.spec.node_name}
@@ -253,7 +252,6 @@ class TestSharedVolumePlanes:
             if sum(1 for p in store_m.list_pods()
                    if p.spec.node_name) >= 48:
                 break
-        bs.flush()
         sched.wait_for_inflight_bindings()
         sharded_placements = {
             p.metadata.name: p.spec.node_name

@@ -321,7 +321,6 @@ class TestCausalStitching:
                     break
             else:
                 pytest.fail("pod never bound")
-            bs.flush()
             code, doc = _http(f"{server.url}/debug/trace")
             assert code == 200
             events = doc["traceEvents"]
