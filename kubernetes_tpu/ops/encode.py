@@ -1003,14 +1003,9 @@ class BatchEncoder:
                 if ci is None:
                     return None
                 pod_sc[bi, ci] = True
-            simple_cons, complex_cons = self._con_match_idx
+            for ci in self.matched_constraints(pod):
+                pod_sc_match[bi, ci] = True
             labels = pod.metadata.labels or {}
-            for kv in labels.items():
-                for ci, con in simple_cons.get(kv, ()):
-                    if pod.namespace == con.namespace:
-                        pod_sc_match[bi, ci] = True
-            for ci, con in complex_cons:
-                pod_sc_match[bi, ci] = con.matches(pod)
 
             def tracked(t) -> Optional[int]:
                 return self._term_index.get(_term_key(t))
@@ -1058,6 +1053,17 @@ class BatchEncoder:
             pref_weight=pref_weight,
             pod_sv=pod_sv,
         )
+
+    def matched_constraints(self, pod: Pod) -> List[int]:
+        """Indices of the tracked spread constraints whose namespace and
+        selector match ``pod``: the pods each one counts. The pod rows'
+        ``pod_sc_match`` and the mirror's spread deltas both read it."""
+        simple_cons, complex_cons = self._con_match_idx
+        out = [ci for kv in (pod.metadata.labels or {}).items()
+               for ci, con in simple_cons.get(kv, ())
+               if pod.namespace == con.namespace]
+        out.extend(ci for ci, con in complex_cons if con.matches(pod))
+        return out
 
     # ------------------------------------------------------------------
     def _static_profile_key(self, pod: Pod) -> tuple:

@@ -29,10 +29,16 @@ The contract has three parts:
 - Expressibility is conservative and arithmetic-exact. A pod delta is
   only scattered when the result is bit-identical to a rebuild:
   the node is in the resident index, the pod matches no tracked
-  spread constraint or (anti-)affinity term and owns none, it has no
-  volumes while CSI attach columns exist, and its memory/ephemeral
-  requests are KiB-aligned (``_kib`` is a ceiling division applied to
-  SUMS at rebuild — per-pod deltas are exact only on aligned values).
+  (anti-)affinity term and owns none, it has no volumes while CSI
+  attach columns exist, and its memory/ephemeral requests are
+  KiB-aligned (``_kib`` is a ceiling division applied to SUMS at
+  rebuild — per-pod deltas are exact only on aligned values).
+  Spread constraints are no obstacle: they are tracked from the pods
+  being scheduled, so a running pod that owns one registers nothing,
+  and a pod a tracked constraint counts (namespace and selector match,
+  not terminating) is ±1 on its node's topology domain — an exact add
+  on every column of that domain in the constraint's ``sc_counts``
+  row. A node without the key counts nowhere, as at rebuild.
   A node update scatters only when old and new differ in nothing but
   ``status.allocatable`` (labels, taints, unschedulable, images all
   equal — anything else touches static masks/scores/topology codes).
@@ -108,9 +114,11 @@ class DeltaJournal:
 
 
 def _pad_pow2(m: int) -> int:
-    """Scatter-entry padding bucket (pow2, min 8): bounds the number of
-    distinct compiled scatter shapes."""
-    p = 8
+    """Scatter-entry padding bucket (pow2, min 1024): bounds the number
+    of distinct compiled scatter shapes, each of which the sidecar's
+    warm-up compiles (``warm_scatter``); a padded small catch-up ships
+    12 KiB."""
+    p = 1024
     while p < m:
         p *= 2
     return p
@@ -125,6 +133,8 @@ class DeviceClusterMirror:
         self._journal = journal
         # telemetry (the mirror[] diag segment reads these)
         self.events_applied = 0
+        # pod deltas expressed through the sc_counts rows
+        self.spread_pods = 0
         self.catch_ups = 0
         self.scatter_bytes_total = 0
         self.reseeds = 0   # full rebuilds AFTER the first seed
@@ -134,6 +144,9 @@ class DeviceClusterMirror:
         # why the last catch_up returned None (see catch_up), else None
         self.last_reason: Optional[str] = None
         self._node_map: Optional[Dict[str, int]] = None
+        # [SC, N] topology code of each plane column per tracked spread
+        # constraint (V = the node lacks the key); None with none tracked
+        self._sc_codes: Optional[np.ndarray] = None
 
     # ------------------------------------------------------------------
     def note_seeded(self, cold: bool, warming: bool, reason: str) -> None:
@@ -143,6 +156,7 @@ class DeviceClusterMirror:
         start or a warm-up — the rebuild counts as a mirror reseed.
         ``reason`` is counted in ``reseed_reasons``."""
         self._node_map = None
+        self._sc_codes = None
         if warming:
             return
         if cold:
@@ -154,11 +168,44 @@ class DeviceClusterMirror:
     def info(self) -> dict:
         return {
             "events": self.events_applied,
+            "spread_pods": self.spread_pods,
             "catch_ups": self.catch_ups,
             "scatter_mb": round(self.scatter_bytes_total / 1e6, 3),
             "reseeds": self.reseeds,
             "reseed_reasons": dict(self.reseed_reasons),
         }
+
+    def warm_scatter(self) -> int:
+        """Compile the state add-scatter for every entry bucket
+        (``_pad_pow2``) a catch-up against the resident encoding can
+        reach, with entries that add nothing, so that no catch-up
+        compiles inside a measured run. A window's entries, one per
+        target after the host-side combine, are at most the pod rows of
+        every node (resource columns, nonzero, pod count) plus every
+        column of each tracked spread constraint's row. Returns the
+        buckets dispatched (0 without resident state)."""
+        sess = self._session
+        backend = sess._active
+        if sess._state is None or sess._cluster is None or \
+                sess._encoder is None or \
+                not hasattr(backend, "scatter_state_add"):
+            return 0
+        enc = sess._encoder
+        cluster = sess._cluster
+        # a window's pod records (two per update) touch at most this
+        # many node columns
+        cols = min(2 * JOURNAL_CAP, cluster.num_real_nodes)
+        most = cols * (len(enc._resource_names) + 3) \
+            + len(enc._constraints or ()) * cluster.allocatable.shape[0]
+        buckets = 0
+        pad = _pad_pow2(1)
+        while pad <= _pad_pow2(most):
+            zeros = np.zeros(pad, dtype=np.int32)
+            sess._state, _ = backend.scatter_state_add(
+                sess._state, zeros, zeros, zeros)
+            buckets += 1
+            pad *= 2
+        return buckets
 
     def _refuse(self, reason: str) -> None:
         """Record why the window falls back to a full reseed."""
@@ -174,9 +221,9 @@ class DeviceClusterMirror:
         full host encode, which is the mirror-off behavior. On None,
         ``last_reason`` names the exit: ``no_scatter``, ``cold``,
         ``journal_gap``, ``translate_error``, ``dispatch_error``, or a
-        record's own (``pod_spread_terms``, ``pod_affinity_terms``,
-        ``pod_volumes``, ``pod_unaligned``, ``node_unknown``,
-        ``node_set_change``, ``node_static_change``, ``external``)."""
+        record's own (``pod_affinity_terms``, ``pod_volumes``,
+        ``pod_unaligned``, ``node_unknown``, ``node_set_change``,
+        ``node_static_change``, ``external``)."""
         sess = self._session
         backend = sess._active
         self.last_reason = None
@@ -199,9 +246,9 @@ class DeviceClusterMirror:
             return self._refuse("translate_error")
         if plan is None:
             return None
-        adds, sets = plan
+        adds, spread, spread_pods, sets = plan
         try:
-            nbytes = self._dispatch(backend, adds, sets)
+            nbytes = self._dispatch(backend, adds, spread, sets)
         except Exception:  # noqa: BLE001
             # the device update may have half-applied: poison the
             # session AND drop the static fingerprint so the rebuild
@@ -212,6 +259,7 @@ class DeviceClusterMirror:
             return self._refuse("dispatch_error")
         self.catch_ups += 1
         self.events_applied += len(recs)
+        self.spread_pods += spread_pods
         self.scatter_bytes_total += nbytes
         return nbytes
 
@@ -228,10 +276,26 @@ class DeviceClusterMirror:
             }
         return self._node_map
 
+    def _spread_codes(self) -> Optional[np.ndarray]:
+        """[SC, N] topology code of every plane column for each tracked
+        spread constraint of the RESIDENT encoding, with the sentinel V
+        for a node that lacks the key — the ``sc_codes`` the state
+        planes were built from. None when no constraint is tracked."""
+        enc = self._session._encoder
+        if not enc._constraints:
+            return None
+        if self._sc_codes is None:
+            keys = [con.key_idx for con in enc._constraints]
+            self._sc_codes = np.minimum(
+                self._session._cluster.topo_codes[:, keys].T,
+                enc._num_values).astype(np.int32)
+        return self._sc_codes
+
     def _translate(
         self, recs: List[DeltaRecord],
-    ) -> Optional[Tuple[list, dict]]:
-        """Journal window → (state add-entries, static set-entries).
+    ) -> Optional[Tuple[list, Optional[np.ndarray], int, dict]]:
+        """Journal window → (state add-entries, spread add-entries as an
+        [M, 3] array or None, pods those express, static set-entries).
         None = some record cannot be expressed bit-exactly against the
         resident encoding space (``last_reason`` says which)."""
         sess = self._session
@@ -242,6 +306,8 @@ class DeviceClusterMirror:
         nmap = self._node_index()
         adds: list = []
         sets: dict = {}
+        spread = _SpreadDelta(self._spread_codes(),
+                              sess._encoder._num_values)
         for rec in recs:
             k = rec.kind
             if k == "assume_bulk":
@@ -250,12 +316,16 @@ class DeviceClusterMirror:
                 # double-count
                 continue
             if k in ("assume", "pod_add"):
-                why = self._pod_delta(adds, rec.a, +1, do, names, nmap)
+                why = self._pod_delta(adds, spread, rec.a, +1, do, names,
+                                      nmap)
             elif k == "pod_del":
-                why = self._pod_delta(adds, rec.a, -1, do, names, nmap)
+                why = self._pod_delta(adds, spread, rec.a, -1, do, names,
+                                      nmap)
             elif k in ("pod_update", "pod_move"):
-                why = self._pod_delta(adds, rec.a, -1, do, names, nmap) \
-                    or self._pod_delta(adds, rec.b, +1, do, names, nmap)
+                why = self._pod_delta(adds, spread, rec.a, -1, do, names,
+                                      nmap) \
+                    or self._pod_delta(adds, spread, rec.b, +1, do, names,
+                                       nmap)
             elif k == "node_update":
                 why = self._node_set(sets, rec.a, rec.b, so, names, nmap)
             elif k in ("node_add", "node_del"):
@@ -266,13 +336,14 @@ class DeviceClusterMirror:
                 why = "external"
             if why is not None:
                 return self._refuse(why)
-        return adds, sets
+        return adds, spread.entries(do), spread.pods, sets
 
-    def _pod_delta(self, out: list, pod, sign: int, do, names,
-                   nmap) -> Optional[str]:
+    def _pod_delta(self, out: list, spread: "_SpreadDelta", pod,
+                   sign: int, do, names, nmap) -> Optional[str]:
         """Append (plane row, node col, value) add-entries for one
-        pod's contribution to the dynamic planes. Returns None, or the
-        reason the pod forces a reseed."""
+        pod's contribution to the dynamic planes, and add its sign to
+        the domains of the spread constraints that count it. Returns
+        None, or the reason the pod forces a reseed."""
         node_name = getattr(pod.spec, "node_name", "") or ""
         if not node_name:
             return None   # unbound pod: no node-plane impact
@@ -284,21 +355,16 @@ class DeviceClusterMirror:
         # per-claim set semantics the additive model cannot replay
         if enc._attach_col and getattr(pod.spec, "volumes", None):
             return "pod_volumes"
-        # pods owning spread/affinity terms contribute to tracked-term
+        # pods owning affinity terms contribute to tracked-term
         # registration and anti-term owner counts
-        if getattr(pod.spec, "topology_spread_constraints", None):
-            return "pod_spread_terms"
         aff = getattr(pod.spec, "affinity", None)
         if aff is not None and (
             getattr(aff, "pod_affinity", None) is not None
             or getattr(aff, "pod_anti_affinity", None) is not None
         ):
             return "pod_affinity_terms"
-        # pods MATCHED by a tracked constraint/term land in the
-        # sc_counts/term_counts value tables
-        for con in (enc._constraints or []):
-            if con.matches(pod):
-                return "pod_spread_terms"
+        # pods MATCHED by a tracked term land in term_counts and the
+        # per-term totals
         for term in (enc._terms or []):
             if term.matches(pod):
                 return "pod_affinity_terms"
@@ -321,6 +387,10 @@ class DeviceClusterMirror:
         if nz.memory:
             out.append((do["nonzero"] + 1, col, sign * _kib(nz.memory)))
         out.append((do["pod_count"], col, sign))
+        # a terminating pod counts toward no spread constraint
+        if spread.codes is not None and \
+                pod.metadata.deletion_timestamp is None:
+            spread.add(enc.matched_constraints(pod), col, sign)
         return None
 
     def _node_set(self, sets: dict, old, new, so, names,
@@ -359,20 +429,26 @@ class DeviceClusterMirror:
         return None
 
     # ------------------------------------------------------------------
-    def _dispatch(self, backend, adds: list, sets: dict) -> int:
+    def _dispatch(self, backend, adds: list, spread: Optional[np.ndarray],
+                  sets: dict) -> int:
         """Ship the translated entries through the backend's donated
         scatter hooks; returns the h2d bytes that actually crossed."""
         sess = self._session
         total = 0
-        if adds:
+        if adds or spread is not None:
             # combine duplicate (row, col) targets host-side (one entry
             # per target keeps the padded bucket small; .at[].add would
-            # accumulate duplicates anyway)
+            # accumulate duplicates anyway). The spread entries are one
+            # per target already, on rows no pod-row entry touches.
             acc: Dict[tuple, int] = {}
             for row, col, val in adds:
                 acc[(row, col)] = acc.get((row, col), 0) + val
-            items = [(rc[0], rc[1], v) for rc, v in acc.items() if v]
-            if items:
+            items = np.asarray(
+                [(rc[0], rc[1], v) for rc, v in acc.items() if v],
+                dtype=np.int32).reshape(-1, 3)
+            if spread is not None:
+                items = np.concatenate([items, spread])
+            if len(items):
                 rows, cols, vals = _pack_entries(items, pad_with_zero=True)
                 sess._state, nb = backend.scatter_state_add(
                     sess._state, rows, cols, vals)
@@ -393,6 +469,41 @@ class DeviceClusterMirror:
         return total
 
 
+class _SpreadDelta:
+    """One window's spread count changes: ``domains[ci, code]`` sums
+    the signs of the pods constraint ``ci`` counts on nodes of topology
+    domain ``code``. ``codes`` is the resident [SC, N] column coding
+    (None when no constraint is tracked); the sentinel column V, a node
+    without the key, is never written."""
+
+    def __init__(self, codes: Optional[np.ndarray], num_values: int):
+        self.codes = codes
+        self.sentinel = num_values
+        self.domains = None if codes is None else np.zeros(
+            (codes.shape[0], num_values + 1), dtype=np.int32)
+        self.pods = 0
+
+    def add(self, matched: List[int], col: int, sign: int) -> None:
+        if not matched:
+            return
+        self.pods += 1
+        for ci in matched:
+            code = self.codes[ci, col]
+            if code != self.sentinel:
+                self.domains[ci, code] += sign
+
+    def entries(self, do: dict) -> Optional[np.ndarray]:
+        """[M, 3] int32 (row, col, val) add-entries: each domain's net
+        change on every column of the domain, in its constraint's
+        ``sc_counts`` row. None when nothing changed."""
+        if self.domains is None or not self.domains.any():
+            return None
+        per_col = np.take_along_axis(self.domains, self.codes, axis=1)
+        ci, col = np.nonzero(per_col)
+        return np.stack([do["sc_counts"] + ci, col, per_col[ci, col]],
+                        axis=1).astype(np.int32)
+
+
 def _seq_equal(a, b) -> bool:
     """Structural equality for api-object lists (taints, images):
     dataclass ``__eq__`` compares by value; fall back to repr so an
@@ -409,18 +520,16 @@ def _seq_equal(a, b) -> bool:
     return repr(a) == repr(b)
 
 
-def _pack_entries(items: list, pad_with_zero: bool):
-    """(row, col, val) triples → padded int32 arrays. Add-scatters pad
-    with (0, 0, 0) (adds nothing); set-scatters repeat the last real
-    entry (same-value duplicate set is deterministic)."""
-    m = len(items)
-    pad = _pad_pow2(m)
-    if pad_with_zero:
-        fill = (0, 0, 0)
-    else:
-        fill = items[-1]
-    items = items + [fill] * (pad - m)
-    arr = np.asarray(items, dtype=np.int32)
+def _pack_entries(items, pad_with_zero: bool):
+    """(row, col, val) triples, a list or an [M, 3] array → padded
+    int32 arrays. Add-scatters pad with (0, 0, 0) (adds nothing);
+    set-scatters repeat the last real entry (same-value duplicate set
+    is deterministic)."""
+    arr = np.asarray(items, dtype=np.int32).reshape(-1, 3)
+    m = len(arr)
+    fill = np.zeros(3, dtype=np.int32) if pad_with_zero else arr[-1]
+    arr = np.concatenate(
+        [arr, np.broadcast_to(fill, (_pad_pow2(m) - m, 3))])
     return (np.ascontiguousarray(arr[:, 0]),
             np.ascontiguousarray(arr[:, 1]),
             np.ascontiguousarray(arr[:, 2]))

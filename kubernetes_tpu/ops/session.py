@@ -450,11 +450,13 @@ class SolverSession:
             and self._node_epoch == self.sched.cache.node_set_seq
         ):
             events0 = self._mirror.events_applied
+            spread0 = self._mirror.spread_pods
             with self._span("solve.scatter") as sp:
                 t_sc = time.monotonic()
                 applied = self._mirror.catch_up(self._last_seq, seq_before)
                 sc_s = time.monotonic() - t_sc
                 sp.set(events=self._mirror.events_applied - events0,
+                       spread=self._mirror.spread_pods - spread0,
                        bytes=applied or 0)
             if applied is not None:
                 scatter_stash = (sc_s, applied)

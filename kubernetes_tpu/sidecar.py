@@ -498,6 +498,9 @@ class TPUBatchScheduler:
             if half < self._chunk:
                 self.session.solve(pods, warming=True, pad_to=half)
                 self._warmed_pads.add(half)
+            # the mirror's catch-up buckets, against the same shapes
+            if self.session._mirror is not None:
+                self.session._mirror.warm_scatter()
             self.session.invalidate()
         except Exception:
             _logger.exception("solver warmup failed (continuing cold)")

@@ -1,8 +1,8 @@
 """The scheduling cycle's stages as tracer spans, on the profiler's clock.
 
 A 48-node cluster with zone and hostname spread pods, driven one
-``run_batch`` pass at a time: the span tree of a pass, the rebuild
-and reseed reason a churn delete between passes leaves, the disabled
+``run_batch`` pass at a time: the span tree of a pass, the scatter a
+churn delete between passes leaves in place of a rebuild, the disabled
 tracer's shared no-op, and the spans' ``TraceAnnotation`` twins in a
 CPU profiler trace, read back by the benchmark's own trace reduction.
 """
@@ -152,9 +152,9 @@ def test_idle_pass_leaves_no_record(cluster, tracer):
 
 def test_discard_after_churn_delete(cluster, tracer):
     """A spread pod deleted between two passes, with a backlog queued:
-    nothing is discarded, and the next pass solves its own drain after
-    a rebuild whose reason is the deleted pod's spread terms, then
-    commits all of it."""
+    nothing is discarded, and the next pass scatters the delete into
+    the resident spread counts, with no rebuild and no reseed, solves
+    its own drain and commits all of it."""
     store, bs = cluster
     mirror = bs.session._mirror
     assert mirror is not None
@@ -162,6 +162,7 @@ def test_discard_after_churn_delete(cluster, tracer):
     # 16 pods solved and committed, 8 more queued
     assert bs.run_batch(pop_timeout=0.0) == 16
     before = dict(mirror.info()["reseed_reasons"])
+    rebuilds = len(_records(tracer, "solve.rebuild"))
     store.delete_pod("default", "run-0")
     assert bs.run_batch(pop_timeout=0.0) == 8
     cycles = _records(tracer, "queue.cycle")
@@ -170,16 +171,13 @@ def test_discard_after_churn_delete(cluster, tracer):
     for c in cycles:
         assert "discarded" not in c[8]
     assert _records(tracer, "sidecar.discard") == []
-    assert cycles[-1][8]["rebuild"] in ("full", "state_only")
-    rebuilds = _records(tracer, "solve.rebuild")
-    assert rebuilds[-1][8]["reason"] == "pod_spread_terms"
-    after = mirror.info()["reseed_reasons"]
-    assert after.get("pod_spread_terms", 0) == \
-        before.get("pod_spread_terms", 0) + 1
-    assert mirror.last_reason == "pod_spread_terms"
-    # the failed catch-up is on the tracer too
+    assert cycles[-1][8]["rebuild"] == "scatter"
+    assert len(_records(tracer, "solve.rebuild")) == rebuilds
+    assert mirror.info()["reseed_reasons"] == before
+    assert mirror.last_reason is None
+    # the catch-up is on the tracer, the delete counted as a spread pod
     scatter = _records(tracer, "solve.scatter")[-1]
-    assert scatter[8]["events"] == 0 and scatter[8]["bytes"] == 0
+    assert scatter[8]["events"] > 0 and scatter[8]["spread"] > 0
     assert bs.run_batch(pop_timeout=0.0) == 0
     assert all(p.spec.node_name for p in store.list_pods())
 

@@ -219,19 +219,21 @@ class TestDeltaJournal:
 class TestPackEntries:
     def test_add_padding_is_zero(self):
         rows, cols, vals = _pack_entries([(3, 7, -5)], pad_with_zero=True)
-        assert rows.shape == (8,) and rows.dtype == np.int32
+        assert rows.shape == (1024,) and rows.dtype == np.int32
         assert (rows[1:] == 0).all() and (vals[1:] == 0).all()
         assert (rows[0], cols[0], vals[0]) == (3, 7, -5)
 
     def test_set_padding_repeats_last(self):
         items = [(1, 2, 9), (4, 5, 6)]
         rows, cols, vals = _pack_entries(items, pad_with_zero=False)
-        assert rows.shape == (8,)
+        assert rows.shape == (1024,)
         assert (rows[2:] == 4).all() and (vals[2:] == 6).all()
 
     def test_pow2_buckets(self):
-        rows, _, _ = _pack_entries([(0, 0, 1)] * 9, pad_with_zero=True)
-        assert rows.shape == (16,)
+        rows, _, _ = _pack_entries([(0, 0, 1)] * 1024, pad_with_zero=True)
+        assert rows.shape == (1024,)
+        rows, _, _ = _pack_entries([(0, 0, 1)] * 1025, pad_with_zero=True)
+        assert rows.shape == (2048,)
 
 
 class TestKillSwitch:
@@ -402,6 +404,186 @@ class TestCarryRestore:
             pytest.skip(f"needs {devices} devices")
         self._check(ShardedBackend(make_mesh(devices, batch_axis=1),
                                    donate=True))
+
+
+ZONE = "topology.kubernetes.io/zone"
+HOST = "kubernetes.io/hostname"
+
+
+def _spread_pod(name, node="", ns="default", owner=True, app="bar",
+                cpu="200m"):
+    """A pod labelled ``app=<app>``; an owner also carries hostname and
+    zone ``DoNotSchedule`` constraints that count ``app=bar`` pods."""
+    w = (MakePod().name(name).namespace(ns).uid(f"u-{ns}-{name}")
+         .label("app", app).req({"cpu": cpu, "memory": "256Mi"}))
+    if owner:
+        w = (w.spread_constraint(10, HOST, "DoNotSchedule", {"app": "bar"})
+             .spread_constraint(10, ZONE, "DoNotSchedule", {"app": "bar"}))
+    if node:
+        w = w.node(node)
+    return w.obj()
+
+
+class TestSpreadCatchUp:
+    """The spread twin of ``TestCarryRestore``: running spread owners,
+    pods only matched by the tracked hostname and zone constraints, a
+    pod in another namespace, a terminating pod, a relabelled pod and a
+    node without the zone label all change under the resident
+    encoding. The catch-up scatters every one of them into the
+    ``sc_counts`` rows, and the state equals a fresh state-only
+    rebuild, bit for bit, on every backend the mirror serves."""
+
+    def _seeded(self, backend=None, warm=False):
+        """A scheduler whose mirror is current after 24 + 16 committed
+        spread pods, with the running pods of every kind bound."""
+        store = ClusterStore()
+        for i in range(10):
+            w = MakeNode().name(f"n{i}").capacity(
+                {"cpu": "8", "memory": "16Gi"})
+            if i < 9:   # n9 has no zone label
+                w = w.label(ZONE, f"z{i % 3}")
+            store.add_node(w.obj())
+        sched, bs = _make_sched(store, max_batch=16, backend=backend)
+        if warm:
+            bs.warmup(sample_pods=[_spread_pod("warm-0")])
+        running = [_spread_pod(f"own-{i}", f"n{i % 10}") for i in range(10)]
+        running += [_spread_pod(f"match-{i}", f"n{(3 * i) % 10}",
+                                owner=False) for i in range(8)]
+        running += [_spread_pod("far-0", "n1", ns="other"),
+                    _spread_pod("plain-0", "n2", owner=False, app="baz")]
+        store.create_pods(running)
+        store.create_pods([_spread_pod(f"w0-{i}") for i in range(24)])
+        _pump(sched, bs)
+        store.create_pods([_spread_pod(f"w1-{i}") for i in range(24)])
+        assert bs.run_batch(pop_timeout=0.1) == 16
+        assert sched.wait_for_inflight_bindings()
+        assert bs.session.mirror_current()
+        return store, sched, bs
+
+    def _check(self, backend=None):
+        from kubernetes_tpu.ops.encode import BatchEncoder
+        from kubernetes_tpu.ops.pallas_solver import _state_planes
+
+        store, sched, bs = self._seeded(backend)
+        try:
+            sess = bs.session
+            mirror = sess._mirror
+            static = sess._static
+            assert static.sc == 2
+            do, _ = _state_planes(static.r, static.sc, static.t, static.sv)
+            n = sess._cluster.allocatable.shape[0]
+
+            def sc_rows(state):
+                planes = np.asarray(state.planes)
+                rows = planes.reshape(planes.shape[0], n)
+                return rows[do["sc_counts"]:do["sc_counts"] + 2].copy()
+
+            sc_before = sc_rows(sess._state)
+            w0 = [p for p in store.list_pods()
+                  if p.metadata.name.startswith("w0") and p.spec.node_name]
+            store.delete_pods(
+                [("default", p.metadata.name) for p in w0[:6]]
+                + [("default", f"own-{i}") for i in (0, 4, 9)]
+                + [("default", f"match-{i}") for i in (0, 3, 5)]
+                + [("other", "far-0"), ("default", "plain-0")])
+            # a new revision of match-1 (on n3) that no constraint counts
+            store.update_pod(_spread_pod("match-1", "n3", owner=False,
+                                         app="baz"))
+            store.create_pods([
+                _spread_pod("match-9", "n9", owner=False),
+                _spread_pod("own-10", "n3"),
+                _spread_pod("far-1", "n2", ns="other", owner=False),
+                MakePod().name("gone-0").uid("u-gone-0").label("app", "bar")
+                .req({"cpu": "100m", "memory": "256Mi"}).node("n5")
+                .terminating().obj(),
+            ])
+            assert not sess.mirror_current()
+            spread0 = mirror.info()["spread_pods"]
+            applied = mirror.catch_up(sess._last_seq,
+                                      sched.cache.mutation_seq)
+            assert applied is not None and mirror.last_reason is None
+            # deleted owners and matched pods, the relabel's matched old
+            # side, the two added; not the other namespace, the
+            # unmatched pods or the terminating one
+            assert mirror.info()["spread_pods"] - spread0 == 6 + 3 + 3 + 1 + 2
+            got = _state_rows(sess._state)
+            assert not np.array_equal(sc_rows(sess._state), sc_before)
+
+            sched.algorithm.update_snapshot()
+            cluster, batch = BatchEncoder(
+                sched.algorithm.snapshot, pad_nodes=sess.pad_nodes,
+            ).encode(sched.queue.pending_pods()[:16], pad_pods=16)
+            be = sess._active
+            if hasattr(be, "prepare_state_only"):
+                fresh = be.prepare_state_only(cluster, batch)
+            else:
+                _static, fresh = be.prepare(cluster, batch)
+            assert np.array_equal(got, _state_rows(fresh))
+        finally:
+            sched.stop()
+
+    def test_pallas(self, monkeypatch):
+        monkeypatch.setenv("KTPU_SOLVER", "pallas")
+        self._check()
+
+    def test_native(self):
+        from kubernetes_tpu.ops import native_backend
+
+        self._check(native_backend.CppBackend())
+
+    @pytest.mark.parametrize("devices", [1, 2, 4])
+    def test_sharded_donated(self, devices):
+        import jax
+
+        from kubernetes_tpu.parallel import ShardedBackend, make_mesh
+
+        if len(jax.devices()) < devices:
+            pytest.skip(f"needs {devices} devices")
+        self._check(ShardedBackend(make_mesh(devices, batch_axis=1),
+                                   donate=True))
+
+    def test_warmup_compiles_every_scatter_bucket(self, monkeypatch):
+        """After the sidecar's warm-up no catch-up compiles a scatter:
+        every entry bucket was dispatched against the same shapes."""
+        from kubernetes_tpu.ops.pallas_solver import _scatter_flat_add_jit
+
+        monkeypatch.setenv("KTPU_SOLVER", "pallas")
+        _scatter_flat_add_jit.clear_cache()
+        store, sched, bs = self._seeded(warm=True)
+        try:
+            sess = bs.session
+            warmed = _scatter_flat_add_jit._cache_size()
+            assert warmed >= 1
+            for k in range(1, 40, 6):
+                store.delete_pods([("default", f"w0-{i}")
+                                   for i in range(k - 6, k) if i >= 0])
+                store.create_pods([_spread_pod(f"add-{k}-{i}", f"n{i}")
+                                   for i in range(k % 10)])
+                assert sess._mirror.catch_up(
+                    sess._last_seq, sched.cache.mutation_seq) is not None
+                sess._last_seq = sched.cache.mutation_seq
+            assert sess._mirror.info()["spread_pods"] > 0
+            assert _scatter_flat_add_jit._cache_size() == warmed
+        finally:
+            sched.stop()
+
+    def test_anti_affinity_owner_refuses(self, monkeypatch):
+        """An anti-affinity owner registers a term: still a reseed."""
+        monkeypatch.setenv("KTPU_SOLVER", "pallas")
+        store, sched, bs = self._seeded()
+        try:
+            sess = bs.session
+            store.create_pods([
+                _spread_pod("match-9", "n4", owner=False),
+                MakePod().name("anti-0").uid("u-anti-0").label("app", "bar")
+                .req({"cpu": "100m", "memory": "256Mi"}).node("n6")
+                .pod_anti_affinity("app", ["bar"], HOST).obj(),
+            ])
+            assert sess._mirror.catch_up(
+                sess._last_seq, sched.cache.mutation_seq) is None
+            assert sess._mirror.last_reason == "pod_affinity_terms"
+        finally:
+            sched.stop()
 
 
 class TestMeshDifferential:
